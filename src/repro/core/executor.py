@@ -42,7 +42,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -257,6 +256,11 @@ class PhaseRun:
     def __init__(self, cfg: ExecConfig, key, pp, arch_cfg: ArchConfig,
                  tokens, spec: ProxySpec, variant=FULL_VARIANT,
                  outer: Ledger | None = None):
+        if cfg.ring.bits >= 64 and jax.default_backend() == "tpu":
+            raise ValueError(
+                "RING64 cannot run on a TPU: XLA has no int64 dot lowering "
+                "there (dot(s64, s64) is UNIMPLEMENTED). Use RING32, the "
+                "TPU ring; RING64 is the CPU oracle.")
         self.cfg = cfg
         self.ring = ring = cfg.ring
         self.key = key
@@ -312,7 +316,6 @@ class PhaseRun:
         self.scale = jnp.asarray(arch_cfg.d_model ** 0.5, jnp.float32)
         from repro.kernels import ops as kops
         self._kops = kops
-        self.smm0 = kops.smm_stats()
         self.results: list[jax.Array] = []
         self.pending: jax.Array | None = None
         self.pending_wi = -1
@@ -334,6 +337,9 @@ class PhaseRun:
                 # weights resident once per phase: each party's share
                 # components on its pod slice, value dims replicated
                 self.pp_sh = sharding.place_party_tree(self.pp_sh)
+        # the kernel-path witness counts this phase's forwards only, not
+        # the probe above (a cold probe traces every combine too)
+        self.smm0 = kops.smm_stats()
         self.t0 = time.time()
 
     def _ctx(self):
@@ -351,12 +357,12 @@ class PhaseRun:
         b0, b1 = wi * self.W, min((wi + 1) * self.W, self.n_batches)
         return b1 - b0
 
-    def _fwd(self, sh, k):
+    def _fwd(self, sh, k, pp_sh):
         cfg = self.cfg
         eng = MPCEngine(ring=self.ring, protocol=self.proto,
                         combine_impl=cfg.combine).with_key(k)
         with fusion.flight_scope(enabled=cfg.fuse):
-            return proxy_entropy(eng, self.pp_sh, self.arch_cfg,
+            return proxy_entropy(eng, pp_sh, self.arch_cfg,
                                  AShare(sh, self.ring, self.proto),
                                  self.spec, self.variant).sh
 
@@ -390,28 +396,33 @@ class PhaseRun:
             with comm.ledger_scope() as wave_led, \
                     comm.wire_tape_scope(self.tape):
                 if cfg.coalesce:
-                    vf = jax.vmap(self._fwd, in_axes=(1, 0), out_axes=1)
+                    vf = jax.vmap(self._fwd, in_axes=(1, 0, None),
+                                  out_axes=1)
                     if cfg.mesh == "shardmap" and dsize > 1 \
                             and lanes % dsize == 0:
                         # one fresh jit per wave: the re-trace is what
                         # fires this wave's comm.record side effects
-                        # (a cached trace would silently skip them)
+                        # (a cached trace would silently skip them).
+                        # The weights are an argument, replicated: as
+                        # closure constants they would be compiled into
+                        # the program
                         in_sh = P(*([None, "data"]
                                     + [None] * (sh.ndim - 2)))
-                        vf = jax.jit(shard_map(
+                        vf = jax.jit(jax.shard_map(
                             vf, mesh=rules.mesh,
-                            in_specs=(in_sh, P("data")),
+                            in_specs=(in_sh, P("data"), P()),
                             out_specs=P(None, "data", None),
-                            check_rep=False))
+                            check_vma=False))
                         used = dsize
                     elif rules is not None:
                         used = len(sh.sharding.device_set)
                     with comm.wave_scope(lanes):
-                        ent = vf(sh, keys)
+                        ent = vf(sh, keys, self.pp_sh)
                 else:
                     if rules is not None:
                         used = len(sh.sharding.device_set)
-                    ent = jnp.stack([self._fwd(sh[:, li], keys[li])
+                    ent = jnp.stack([self._fwd(sh[:, li], keys[li],
+                                               self.pp_sh)
                                      for li in range(lanes)], axis=1)
             self.phase_led.records.extend(wave_led.records)
             if self.outer is not None:

@@ -27,6 +27,7 @@ re-run resumes after the last completed phase instead of re-scoring.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import json
@@ -228,7 +229,9 @@ def selection_plan(key, target_params, cfg: ArchConfig, pool_tokens,
                 batch=min(sel.score_batch, len(surviving)), fingerprint=fp)
             exec_reports.extend(reports)
             phase_scores.append(np.asarray(ent_sh.sh))
-            with x64_scope():      # quickselect compares int64 shares
+            # RING64 quickselect compares int64 shares
+            with (x64_scope() if ent_sh.ring.bits >= 64
+                  else contextlib.nullcontext()):
                 # fused runs issue per-wave comparison batches and let
                 # the flight batcher fuse them into one flight/partition
                 qs_wave = sel.executor.wave if sel.executor.fuse else 1
@@ -237,9 +240,11 @@ def selection_plan(key, target_params, cfg: ArchConfig, pool_tokens,
                                                       wave=qs_wave)
                 # backend-aware reconstruction: pass the Share (MAC'd
                 # schemes' extra rows are not value components)
-                appraisal = float(jnp.mean(
-                    reconstruct(ent_sh[np.asarray(top_local)])
-                    .astype(jnp.float64) / ent_sh.ring.scale))
+                top_enc = np.asarray(
+                    reconstruct(ent_sh[np.asarray(top_local)]))
+            # float64 on the host: the TPU has no float64 arithmetic
+            appraisal = float(np.mean(top_enc.astype(np.float64)
+                                      / ent_sh.ring.scale))
         else:
             ents = _score_clear(sel.engine, pp, cfg, tok, ph, sel.variant)
             top_local = np.argsort(ents)[-keep:]

@@ -8,7 +8,8 @@
                        replaces — the Oracle's scoring op)
   ssd.py               Mamba-2 chunked SSD with VMEM-resident state carry
   rg_lru.py            RG-LRU linear recurrence, chunked time tiles
-  secure_matmul.py     int32-ring Beaver matmul combine (TPU MPC path)
+  secure_matmul.py     int32-ring Beaver matmul combine on int8 digits
+                       (TPU MPC path)
 
 Each kernel: pl.pallas_call + explicit BlockSpec VMEM tiling. ops.py is
 the jit'd dispatch wrapper (interpret=True off-TPU); ref.py holds the

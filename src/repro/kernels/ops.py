@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
+from repro.parallel import sharding
 from repro.kernels.mlp_softmax_attn import mlp_softmax_attn as _msa
 from repro.kernels.flash_attn import flash_attn as _fa
 from repro.kernels.entropy_head import entropy_head as _eh
@@ -121,23 +122,35 @@ def _warn_fallback(shape) -> None:
             tuple(shape))
 
 
+def _partition_mesh():
+    """The ambient executor mesh when this call is not yet per device
+    (outside any shard_map body): the kernel must be shard_mapped over
+    it, since a Mosaic call cannot be partitioned automatically."""
+    r = sharding.current_rules()
+    if r is None or r.mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return r.mesh
+
+
 def secure_matmul(eps, dlt, a_sh, b_sh, c_sh, *, impl="auto", pad=True,
                   **kw):
     """Beaver post-open combine, both parties fused (MPC hot path).
 
-    Non-tileable shapes are zero-PADDED to the next block multiple by
-    default — exact in wrapping int32 ring arithmetic (zero rows/cols
-    contribute zero to every product term and the padded output region
-    is sliced away), so smoke geometries exercise the kernel instead of
-    silently dropping to the reference. `pad=False` restores the old
-    behaviour: fall back to the jnp reference, logged once per process
-    and counted in `smm_stats()` so silent-cap drops stay visible.
+    Output dims (M, N) that do not tile are zero-PADDED to the next
+    block multiple by default — exact in wrapping int32 ring arithmetic
+    (zero rows/cols contribute zero to every product term and the padded
+    output region is sliced away), so smoke geometries exercise the
+    kernel instead of silently dropping to the reference; the kernel
+    pads its own contraction. `pad=False` restores the old behaviour:
+    fall back to the jnp reference, logged once per process and counted
+    in `smm_stats()` so silent-cap drops stay visible.
     """
     m = _mode(impl)
     mm, kk = eps.shape
     nn = dlt.shape[1]
-    blocks = (kw.get("bm", 128), kw.get("bn", 128), kw.get("bk", 128))
-    dims = (mm, nn, kk)
+    blocks = (kw.get("bm", 128), kw.get("bn", 128))
+    dims = (mm, nn)
     tiled = all(_tileable(d, blk) for d, blk in zip(dims, blocks))
     if m != "ref" and not tiled and not pad:
         _warn_fallback((mm, kk, nn))
@@ -149,15 +162,16 @@ def secure_matmul(eps, dlt, a_sh, b_sh, c_sh, *, impl="auto", pad=True,
             _ref.secure_matmul_combine(eps, dlt, a_sh[1], b_sh[1], c_sh[1], 1),
         ])
     if not tiled:
-        pm, pn, pk = (_pad_target(d, blk) for d, blk in zip(dims, blocks))
-        eps = jnp.pad(eps, ((0, pm - mm), (0, pk - kk)))
-        dlt = jnp.pad(dlt, ((0, pk - kk), (0, pn - nn)))
-        a_sh = jnp.pad(a_sh, ((0, 0), (0, pm - mm), (0, pk - kk)))
-        b_sh = jnp.pad(b_sh, ((0, 0), (0, pk - kk), (0, pn - nn)))
+        pm, pn = (_pad_target(d, blk) for d, blk in zip(dims, blocks))
+        eps = jnp.pad(eps, ((0, pm - mm), (0, 0)))
+        dlt = jnp.pad(dlt, ((0, 0), (0, pn - nn)))
+        a_sh = jnp.pad(a_sh, ((0, 0), (0, pm - mm), (0, 0)))
+        b_sh = jnp.pad(b_sh, ((0, 0), (0, 0), (0, pn - nn)))
         c_sh = jnp.pad(c_sh, ((0, 0), (0, pm - mm), (0, pn - nn)))
         _smm_stats["padded"] += 1
     _smm_stats["kernel"] += 1
-    z = _smm(eps, dlt, a_sh, b_sh, c_sh, interpret=(m == "interpret"), **kw)
+    z = _smm(eps, dlt, a_sh, b_sh, c_sh, interpret=(m == "interpret"),
+             mesh=_partition_mesh(), **kw)
     if not tiled:
         z = z[:, :mm, :nn]
     return z

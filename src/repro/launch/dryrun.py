@@ -26,7 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs.base import (ARCH_IDS, SHAPES, ShapeSpec, load_arch,  # noqa: E402
                                 cell_is_applicable)
-from repro.launch.mesh import make_production_mesh, data_shards  # noqa: E402
+from repro.launch.mesh import auto_mesh, make_production_mesh, data_shards  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.optim.adamw import AdamWConfig, adamw_update, init_opt_state  # noqa: E402
 from repro.parallel.sharding import ShardRules, param_specs, rules_scope  # noqa: E402
@@ -323,7 +323,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         # so model divides 40 q-heads and 8 kv-heads evenly
         shape_ = (2, 32, 8) if multi_pod else (32, 8)
         axes_ = ("pod", "data", "model") if multi_pod else ("data", "model")
-        mesh = jax.make_mesh(shape_, axes_)
+        mesh = auto_mesh(shape_, axes_)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     # ZeRO-3 only where there is optimizer state to shard; serving keeps

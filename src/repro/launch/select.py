@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import getpass
 import os
 import tempfile
 import time
@@ -104,8 +103,9 @@ def run(seed: int = 0, n_pool: int = 800, budget: float = 0.2,
     ring = RING32 if ring_bits == 32 else RING64
     engine = MPCEngine(ring=ring, protocol=protocol) if mode == "mpc" \
         else ClearEngine()
-    ckpt_dir = os.path.join(tempfile.gettempdir(),
-                            f"selectformer_phases_{getpass.getuser()}")
+    # phase checkpoints are fingerprinted per run, so one shared dir
+    # never resumes a different experiment
+    ckpt_dir = os.path.join(tempfile.gettempdir(), "selectformer_phases")
     sel = SelectionConfig(
         phases=[ProxySpec(1, 2, 2, 0.4), ProxySpec(2, 4, 8, 1.0)],
         budget_frac=budget, boot_frac=0.05, engine=engine,
@@ -179,7 +179,8 @@ def main() -> None:
                     help="disable the flight batcher (fused round "
                          "compression is the default; mpc/fusion.py)")
     ap.add_argument("--ring", type=int, choices=[64, 32], default=64,
-                    help="MPC ring: 64 (CrypTen oracle) or 32 (TPU)")
+                    help="MPC ring: 64 (CrypTen oracle, CPU only) or 32 "
+                         "(the TPU ring)")
     ap.add_argument("--protocol",
                     choices=["2pc", "3pc", "spdz2pc", "aby3trunc"],
                     default="2pc",

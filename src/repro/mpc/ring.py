@@ -76,7 +76,5 @@ RING32 = RingSpec("ring32", jnp.int32, 32, 12)
 def x64_scope():
     """Context manager enabling 64-bit jnp types — RING64 arithmetic (and
     any op on its int64 shares, e.g. comparisons in QuickSelect) must run
-    inside this scope or XLA demotes results to 32 bits. Wraps
-    jax.experimental.enable_x64 (the jax.enable_x64 alias was removed)."""
-    from jax.experimental import enable_x64
-    return enable_x64()
+    inside this scope or XLA demotes results to 32 bits."""
+    return jax.enable_x64(True)

@@ -184,6 +184,23 @@ class TestRing32:
                        if r.op.startswith("beaver"))
         assert beaver32 * 2 == beaver64
 
+    @pytest.mark.parametrize("ring_name", ["ring64", "ring32"])
+    def test_tpu_refuses_ring64_at_construction(self, pp, pool, ring_name,
+                                                monkeypatch):
+        """XLA has no int64 dot on the TPU: a RING64 phase there fails
+        at PhaseRun construction, naming the cause, not deep inside a
+        trace; RING32 constructs (the backend is steered in the test)."""
+        from repro.core.executor import PhaseRun
+        from repro.mpc.ring import RING64
+        ring = {"ring64": RING64, "ring32": RING32}[ring_name]
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = ExecConfig(wave=WAVE, batch=BATCH, ring=ring)
+        if ring is RING64:
+            with pytest.raises(ValueError, match="int64 dot"):
+                PhaseRun(cfg, K, pp, CFG, pool, SPEC)
+        else:
+            assert PhaseRun(cfg, K, pp, CFG, pool, SPEC).ring is RING32
+
 
 # ---------------------------------------------------------------------------
 # 2. schedule invariance / serial equivalence

@@ -124,6 +124,49 @@ def test_secure_matmul_exact(m, k, n):
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
+I32_EDGES = np.array([np.iinfo(np.int32).min, -1, np.iinfo(np.int32).max,
+                      0, 1, -128, 127, 128, -129], np.int64)
+
+
+def _edge_ring(rng, shape):
+    """Full-range int32 values with the digit split's edge cases mixed
+    in: INT32_MIN, -1, INT32_MAX and the byte boundaries."""
+    x = rng.integers(np.iinfo(np.int32).min, np.iinfo(np.int32).max,
+                     shape, endpoint=True)
+    edge = rng.random(shape) < 0.3
+    return jnp.asarray(np.where(edge, rng.choice(I32_EDGES, shape), x),
+                       jnp.int32)
+
+
+def test_int8_digits_reconstruct_mod_2_32():
+    from repro.kernels.secure_matmul import int8_digits
+    x = jnp.asarray(I32_EDGES, jnp.int32)
+    d = np.asarray(int8_digits(x)).astype(np.int64)
+    assert d.min() >= -128 and d.max() <= 127
+    back = sum(d[i] << (8 * i) for i in range(4))
+    assert np.array_equal(back % 2 ** 32, I32_EDGES % 2 ** 32)
+
+
+@pytest.mark.parametrize("m,k,n,blocks", [
+    (256, 256, 128, {}),                       # tileable, 128 blocks
+    (16, 32, 8, dict(bm=8, bn=8, bk=16)),      # tileable, small blocks
+    (8, 64, 2, {}),                            # whole-array blocks
+    (24, 40, 20, dict(bm=16, bn=16, bk=16)),   # padded on every dim
+    (130, 200, 136, {}),                       # padded past one block
+])
+def test_secure_matmul_digit_kernel_bitwise(m, k, n, blocks):
+    """The int8-digit kernel equals the int32 reference bit for bit on
+    full-range operands, edge values included."""
+    rng = np.random.default_rng(m * k + n)
+    eps, dlt = _edge_ring(rng, (m, k)), _edge_ring(rng, (k, n))
+    a, b = _edge_ring(rng, (2, m, k)), _edge_ring(rng, (2, k, n))
+    c = _edge_ring(rng, (2, m, n))
+    got = ops.secure_matmul(eps, dlt, a, b, c, impl="interpret", **blocks)
+    want = jnp.stack([ref.secure_matmul_combine(eps, dlt, a[p], b[p], c[p], p)
+                      for p in (0, 1)])
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_secure_matmul_implements_beaver():
     """Kernel combine + reconstruction == plain ring matmul x@y."""
     rng = np.random.default_rng(0)
