@@ -1,0 +1,10 @@
+"""Percent of the traced window in which no operation ran on the device:
+1 - (union of the device's op intervals / window), mean over the chips
+used. None without a device trace."""
+
+
+def read(rec):
+    tr = rec.trace
+    if tr is None or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
