@@ -226,9 +226,9 @@ def smoke_execute(protocol: str = "2pc") -> dict:
         out[rname] = {"eager_rounds": e.rounds, "fused_rounds": pb.rounds,
                       "round_reduction": red, "bitwise_identical": True,
                       "ledger_agrees": True, "mirror_exact": True,
-                      # measured device-side makespan of the fused phase
-                      # (per-wave dispatch/ready stamps, PhaseReport.device)
-                      "device_makespan_s": reports["fused"].device_makespan_s,
+                      # host-clock wall time of the fused phase's wave
+                      # loop (per-wave stamps, PhaseReport.device)
+                      "waves_wall_s": reports["fused"].waves_wall_s,
                       "offline_nbytes": pb.offline_nbytes,
                       "trunc_events": trunc_events,
                       "trunc_events_pr4": base_events,
@@ -258,8 +258,8 @@ def mesh_smoke() -> dict:
         demonstrably run through kernels/ops.secure_matmul (kernel-path
         dispatch counter > 0, ref-fallback counter == 0) instead of the
         silent jnp reference
-      * device_makespan_s > 0 measured from the double-buffer loop's
-        per-wave dispatch/ready stamps
+      * waves_wall_s > 0 from the double-buffer loop's per-wave
+        host-clock dispatch/ready stamps
     Geometry: 64 candidates / batch 8 / wave 4 -> 2 waves x 4 lanes, so
     the lane count divides the data axis on an 8-device mesh (pod 2 x
     data 4)."""
@@ -278,7 +278,7 @@ def mesh_smoke() -> dict:
     ref = np.asarray(ex0.score_phase(key, pp, cfg, pool, spec).sh)
     rep0 = ex0.reports[-1]
     assert rep0.agrees(), "mesh: single-device reference ledger diverged"
-    out["none"] = {"device_makespan_s": rep0.device_makespan_s,
+    out["none"] = {"waves_wall_s": rep0.waves_wall_s,
                    "wall_s": rep0.wall_s}
 
     for mode in ("host", "shardmap"):
@@ -290,8 +290,8 @@ def mesh_smoke() -> dict:
         assert np.array_equal(ref, np.asarray(ent.sh)), \
             f"mesh={mode}: sharded execution changed entropy scores"
         assert rep.agrees(), f"mesh={mode}: ledger_agrees failed"
-        assert dev.device_makespan_s > 0.0, \
-            f"mesh={mode}: no measured device makespan"
+        assert dev.waves_wall_s > 0.0, \
+            f"mesh={mode}: no wave loop wall time"
         assert dev.combine_kernel > 0, \
             f"mesh={mode}: fused RING32 combines never hit the " \
             f"secure_matmul kernel (interpret mode)"
@@ -307,7 +307,7 @@ def mesh_smoke() -> dict:
             "ledger_agrees": True,
             "n_devices": dev.n_devices,
             "mesh_axes": dev.mesh_axes,
-            "device_makespan_s": dev.device_makespan_s,
+            "waves_wall_s": dev.waves_wall_s,
             "wall_s": rep.wall_s,
             "combine_kernel": dev.combine_kernel,
             "combine_ref": dev.combine_ref,
@@ -524,7 +524,7 @@ def main(argv=None) -> int:
                          "devices on CPU) in both host-GSPMD and "
                          "shard_map placements, gating bitwise scores, "
                          "ledger agreement, the secure_matmul kernel "
-                         "combine path, and a measured device_makespan_s "
+                         "combine path, and the wave loop's waves_wall_s "
                          "(requires --smoke; run under XLA_FLAGS="
                          "--xla_force_host_platform_device_count=8)")
     ap.add_argument("--net", choices=sorted(PROFILES), default="wan",
@@ -594,7 +594,7 @@ def main(argv=None) -> int:
             result[f"smoke_{args.protocol}"] = smoke_execute(args.protocol)
         if args.mesh:
             # device-mesh gates: wave/party axes physically sharded,
-            # kernel-path combines, measured device_makespan_s
+            # kernel-path combines, the wave loop's waves_wall_s
             result["mesh"] = mesh_smoke()
         if args.wire != "none":
             # real-wire gates: both party counts (2pc duplex pair, 3pc
@@ -697,7 +697,7 @@ def main(argv=None) -> int:
             m = mv[mode]
             print(f"mesh[{mode}] devices={m['n_devices']} "
                   f"axes={m['mesh_axes']} "
-                  f"device_makespan={m['device_makespan_s']:.3f}s "
+                  f"waves_wall={m['waves_wall_s']:.3f}s "
                   f"kernel_combines={m['combine_kernel']} "
                   f"padded={m['combine_padded']}")
     if "wire" in result and not args.csv:

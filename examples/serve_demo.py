@@ -21,7 +21,7 @@ from repro.launch.serve import ServeConfig, Server, Request  # noqa: E402
 # the SERVE/SELECT shared per-phase report shape (PhaseReport.as_dict)
 PHASE_KEYS = {"n_batches", "n_waves", "protocol", "lat_rounds",
               "bw_rounds", "nbytes", "offline_nbytes", "makespan_wan_s",
-              "wall_s", "device_makespan_s", "device", "wire"}
+              "wall_s", "waves_wall_s", "device", "wire"}
 
 
 def appraise_demo() -> None:

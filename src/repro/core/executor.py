@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import time
 
 import numpy as np
@@ -50,11 +51,15 @@ from repro.core import proxy as proxy_mod
 from repro.core.proxy import ProxySpec
 from repro.engine import MPCEngine, cached_probe, proxy_entropy
 from repro.engine.base import FULL_VARIANT
+from repro.spans import span
 from repro.mpc import comm, fusion, protocols
 from repro.mpc.comm import DeviceReport, Ledger, NetProfile, WaveTiming
 from repro.mpc.ring import RING64, RingSpec, x64_scope
 from repro.mpc.sharing import AShare, share
 from repro.parallel import sharding
+
+# serial number of each PhaseRun: the `run` of its spans
+_RUN_IDS = itertools.count()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,15 +143,15 @@ class PhaseReport:
     # byte counts, payload digests
     wire: object | None = None
     # device-side outcome (comm.DeviceReport): per-wave dispatch/ready
-    # timestamps from the double-buffer loop, mesh placement, and the
-    # secure_matmul kernel-vs-ref dispatch counters for the phase
+    # host-clock stamps from the double-buffer loop, mesh placement, and
+    # the secure_matmul kernel-vs-ref dispatch counters for the phase
     device: DeviceReport | None = None
 
     @property
-    def device_makespan_s(self) -> float:
-        """Measured device-side makespan (first dispatch -> last wave
-        ready) — the compute twin of the wire's wire_makespan_s."""
-        return self.device.device_makespan_s if self.device else 0.0
+    def waves_wall_s(self) -> float:
+        """Host-clock wall time of the wave loop (first wave's forward
+        start -> last wave ready)."""
+        return self.device.waves_wall_s if self.device else 0.0
 
     def agrees(self) -> bool:
         """Realized flights == the makespan model's inputs, exactly."""
@@ -173,9 +178,9 @@ class PhaseReport:
             "offline_nbytes": self.ledger.offline_nbytes,
             "makespan_wan_s": self.makespan(comm.PROFILES["wan"]),
             "wall_s": self.wall_s,
-            # measured device-side makespan + mesh placement
+            # wave loop wall time + mesh placement
             # (comm.DeviceReport; per-wave stamps in "device")
-            "device_makespan_s": self.device_makespan_s,
+            "waves_wall_s": self.waves_wall_s,
             "device": self.device.as_dict() if self.device is not None
                       else None,
             # real-wire measurement when ExecConfig.wire != "none"
@@ -319,8 +324,9 @@ class PhaseRun:
         self.results: list[jax.Array] = []
         self.pending: jax.Array | None = None
         self.pending_wi = -1
+        self.run_id = next(_RUN_IDS)
 
-        with self._ctx():
+        with self._ctx(), span("phase.setup", run=self.run_id):
             self.pp_sh = proxy_mod.share_proxy(
                 jax.random.fold_in(key, 1), pp, ring, proto)
             self.batch_keys = jax.random.split(
@@ -340,7 +346,7 @@ class PhaseRun:
         # the kernel-path witness counts this phase's forwards only, not
         # the probe above (a cold probe traces every combine too)
         self.smm0 = kops.smm_stats()
-        self.t0 = time.time()
+        self.t0 = time.perf_counter()
 
     def _ctx(self):
         """The ambient scopes every step runs under — re-entered per
@@ -373,28 +379,33 @@ class PhaseRun:
         cfg = self.cfg
         B, W, seq = self.B, self.W, self.seq
         rules, dsize = self.rules, self.dsize
+        run = self.run_id
         with self._ctx():
             b0, b1 = wi * W, min((wi + 1) * W, self.n_batches)
             lanes = b1 - b0
-            wave_tok = jnp.asarray(
-                self.tok[b0 * B:b1 * B]).reshape(lanes, B, seq)
-            x = jnp.take(self.pp["embed"], wave_tok, axis=0) * self.scale
-            x_sh = share(jax.random.fold_in(self.key, 100 + wi),
-                         x.astype(jnp.float32), self.ring, self.proto)
-            w_start = time.time() - self.t0
-            # party axis -> pod, wave axis -> data: a real device_put
-            # on a mesh; without one, the legacy no-op annotation
-            if rules is not None:
-                sh = sharding.place(x_sh.sh, "pod", "wave", "batch",
-                                    None, None)
-            else:
-                sh = sharding.shard(x_sh.sh, "pod", "wave", "batch",
-                                    None, None)
+            with span("wave.input", run=run, wave=wi):
+                wave_tok = jnp.asarray(
+                    self.tok[b0 * B:b1 * B]).reshape(lanes, B, seq)
+                x = jnp.take(self.pp["embed"], wave_tok, axis=0) * self.scale
+                x_sh = share(jax.random.fold_in(self.key, 100 + wi),
+                             x.astype(jnp.float32), self.ring, self.proto)
+                # party axis -> pod, wave axis -> data: a real device_put
+                # on a mesh; without one, the legacy no-op annotation
+                if rules is not None:
+                    sh = sharding.place(x_sh.sh, "pod", "wave", "batch",
+                                        None, None)
+                else:
+                    sh = sharding.shard(x_sh.sh, "pod", "wave", "batch",
+                                        None, None)
             keys = self.batch_keys[b0:b1]
             used = 1
 
-            with comm.ledger_scope() as wave_led, \
+            # start_s and dispatch_s are taken at the edges of this span:
+            # dispatch_s - start_s is its duration, on the host clock
+            with span("wave.forward", run=run, wave=wi), \
+                    comm.ledger_scope() as wave_led, \
                     comm.wire_tape_scope(self.tape):
+                w_start = time.perf_counter() - self.t0
                 if cfg.coalesce:
                     vf = jax.vmap(self._fwd, in_axes=(1, 0, None),
                                   out_axes=1)
@@ -424,43 +435,50 @@ class PhaseRun:
                     ent = jnp.stack([self._fwd(sh[:, li], keys[li],
                                                self.pp_sh)
                                      for li in range(lanes)], axis=1)
-            self.phase_led.records.extend(wave_led.records)
-            if self.outer is not None:
-                self.outer.records.extend(wave_led.records)
+                self.phase_led.records.extend(wave_led.records)
+                if self.outer is not None:
+                    self.outer.records.extend(wave_led.records)
 
-            ent = ent.reshape(self.n_parties, lanes * B)
-            self.dev.waves.append(WaveTiming(
-                wave=wi, lanes=lanes, devices_used=used,
-                start_s=w_start, dispatch_s=time.time() - self.t0))
+                ent = ent.reshape(self.n_parties, lanes * B)
+                self.dev.waves.append(WaveTiming(
+                    wave=wi, lanes=lanes, devices_used=used,
+                    start_s=w_start,
+                    dispatch_s=time.perf_counter() - self.t0))
             # double buffer: block on wave i-1 only after dispatching
             # i, so its wire time overlaps this wave's local compute
             if self.pending is not None:
-                jax.block_until_ready(self.pending)
-                self.dev.waves[self.pending_wi].ready_s = \
-                    time.time() - self.t0
+                self._wait(self.pending, self.pending_wi)
                 self.pending = None
             if cfg.overlap:
                 self.pending, self.pending_wi = ent, wi
             else:
-                jax.block_until_ready(ent)
-                self.dev.waves[wi].ready_s = time.time() - self.t0
+                self._wait(ent, wi)
             self.results.append(ent)
+
+    def _wait(self, ent: jax.Array, wi: int) -> None:
+        """Block on wave `wi`'s result and stamp its ready time."""
+        with span("wave.wait", run=self.run_id, wave=wi):
+            jax.block_until_ready(ent)
+            self.dev.waves[wi].ready_s = time.perf_counter() - self.t0
 
     def drain(self) -> None:
         """Block the tail pending wave (the loop's final barrier)."""
         if self.pending is not None:
             with self._ctx():
-                jax.block_until_ready(self.pending)
-            self.dev.waves[self.pending_wi].ready_s = time.time() - self.t0
+                self._wait(self.pending, self.pending_wi)
             self.pending = None
 
     def finish(self) -> tuple[AShare, PhaseReport]:
         """Concatenate scores, reconcile/replay the wire tape, and seal
         the PhaseReport. Call after every wave dispatched + drain()."""
+        with span("phase.finish", run=self.run_id):
+            return self._finish()
+
+    def _finish(self) -> tuple[AShare, PhaseReport]:
         cfg = self.cfg
         with self._ctx():
             out = jnp.concatenate(self.results, axis=1)[:, :self.n]
-        wall_s = time.time() - self.t0
+        wall_s = time.perf_counter() - self.t0
         smm1 = self._kops.smm_stats()
         dev = self.dev
         dev.combine_kernel = smm1["kernel"] - self.smm0["kernel"]

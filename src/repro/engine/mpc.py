@@ -35,6 +35,7 @@ from repro.engine.forward import _mlp_at
 from repro.mpc import compare, fusion, nonlinear, protocols, ops as mops
 from repro.mpc.ring import RING64, RingSpec
 from repro.mpc.sharing import Share
+from repro.spans import span
 
 
 def _ax(axis: int) -> int:
@@ -100,7 +101,8 @@ class MPCEngine:
         if self._key is None:
             raise ValueError("MPCEngine needs a PRNG seed: call "
                              "engine.with_key(key) before the forward")
-        k = jax.random.fold_in(self._key, self._ctr)
+        with span("mpc.key"):
+            k = jax.random.fold_in(self._key, self._ctr)
         self._ctr += 1
         return k
 

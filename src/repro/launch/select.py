@@ -226,8 +226,8 @@ def main() -> None:
                          "--xla_force_host_platform_device_count=N on "
                          "CPU); 'shardmap' splits wave lanes across the "
                          "data axis under jax.shard_map. Each phase "
-                         "report gains a measured device_makespan_s "
-                         "(mode=mpc)")
+                         "report gains the wave loop's host-clock "
+                         "waves_wall_s (mode=mpc)")
     ap.add_argument("--combine", choices=["auto", "pallas", "interpret",
                                           "ref"],
                     default="auto",
@@ -261,8 +261,9 @@ def main() -> None:
         if meshed:
             d0 = meshed[0]["device"]
             print(f"[select] device mesh ({d0['placement']}): "
-                  f"{d0['n_devices']} devices {d0['mesh_axes']}; measured "
-                  + ", ".join(f"{p['device_makespan_s']:.3f}s"
+                  f"{d0['n_devices']} devices {d0['mesh_axes']}; wave "
+                  "loops (host clock) "
+                  + ", ".join(f"{p['waves_wall_s']:.3f}s"
                               for p in meshed))
         wired = [p["wire"] for p in ph if p.get("wire")]
         if wired:

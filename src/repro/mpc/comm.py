@@ -151,12 +151,15 @@ class Ledger:
 
 @dataclasses.dataclass
 class WaveTiming:
-    """Device-side timestamps of one executed wave (seconds relative to
-    the phase's t0). `start_s` is when dispatch of the wave's forward
-    began, `dispatch_s` when the (async) dispatch returned, `ready_s`
-    when `block_until_ready` on the wave's result returned — under the
-    double-buffered schedule that is one wave later than its dispatch,
-    so ready - start includes the overlap the schedule is buying."""
+    """Host-clock stamps of one executed wave (`time.perf_counter`
+    seconds relative to the phase's t0), taken at the edges of the
+    wave's `sf.wave.*` spans (repro/spans.py). `start_s` is when
+    dispatch of the wave's forward began, `dispatch_s` when the (async)
+    dispatch returned, `ready_s` when `block_until_ready` on the wave's
+    result returned — under the double-buffered schedule that is one
+    wave later than its dispatch, so ready - start includes the overlap
+    the schedule is buying. None of them is a device time: the device
+    trace gives that."""
     wave: int
     lanes: int
     devices_used: int
@@ -185,9 +188,9 @@ class DeviceReport:
     combine_padded: int = 0
 
     @property
-    def device_makespan_s(self) -> float:
-        """Measured device-side makespan: first dispatch start to last
-        wave ready, from the double-buffer loop's own timestamps."""
+    def waves_wall_s(self) -> float:
+        """Host-clock wall time of the wave loop: first wave's forward
+        start to last wave ready, from the loop's own stamps."""
         if not self.waves:
             return 0.0
         return (max(w.ready_s for w in self.waves)
@@ -198,7 +201,7 @@ class DeviceReport:
             "placement": self.placement,
             "n_devices": self.n_devices,
             "mesh_axes": dict(self.mesh_axes),
-            "device_makespan_s": self.device_makespan_s,
+            "waves_wall_s": self.waves_wall_s,
             "combine_kernel": self.combine_kernel,
             "combine_ref": self.combine_ref,
             "combine_padded": self.combine_padded,

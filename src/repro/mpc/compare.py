@@ -22,6 +22,7 @@ import jax
 
 from repro.mpc.sharing import Share, reconstruct, share_encoded
 from repro.mpc import comm, ops
+from repro.spans import span
 
 CMP_ROUNDS = 8          # paper §4.1
 CMP_BYTES = 432         # per scalar comparison
@@ -42,12 +43,13 @@ def lt_zero(x: Share, key: jax.Array) -> Share:
     forcing a truncation first; and a bit at exponent 0 multiplies into
     any share exactly (fb + 0 = fb) — ReLU and tournament-max selection
     become truncation-free."""
-    n = _numel(x.shape)
-    comm.record("secure_cmp", rounds=CMP_ROUNDS, nbytes=CMP_BYTES * n,
-                numel=n, tag="lat")
-    v = reconstruct(x)                         # functionality boundary
-    bit = (v < 0).astype(x.ring.dtype)
-    return share_encoded(key, bit, x.ring, x.proto, fb=0)
+    with span("mpc.cmp"):
+        n = _numel(x.shape)
+        comm.record("secure_cmp", rounds=CMP_ROUNDS, nbytes=CMP_BYTES * n,
+                    numel=n, tag="lat")
+        v = reconstruct(x)                     # functionality boundary
+        bit = (v < 0).astype(x.ring.dtype)
+        return share_encoded(key, bit, x.ring, x.proto, fb=0)
 
 
 def le(x: Share, y: Share, key: jax.Array) -> Share:
@@ -56,11 +58,12 @@ def le(x: Share, y: Share, key: jax.Array) -> Share:
 
 def reveal_lt(x: Share, y: Share) -> jax.Array:
     """Public bit x<y — what QuickSelect consumes (outcome revealed)."""
-    d = ops.sub(x, y)
-    n = _numel(d.shape)
-    comm.record("secure_cmp_reveal", rounds=CMP_ROUNDS, nbytes=CMP_BYTES * n,
-                numel=n, tag="lat")
-    return reconstruct(d) < 0
+    with span("mpc.cmp"):
+        d = ops.sub(x, y)
+        n = _numel(d.shape)
+        comm.record("secure_cmp_reveal", rounds=CMP_ROUNDS,
+                    nbytes=CMP_BYTES * n, numel=n, tag="lat")
+        return reconstruct(d) < 0
 
 
 def relu(x: Share, key: jax.Array) -> Share:

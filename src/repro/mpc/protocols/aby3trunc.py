@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from repro.mpc import comm
 from repro.mpc.protocols.base import numel
 from repro.mpc.protocols.replicated3pc import Replicated3PC
+from repro.spans import span
 
 
 class ABY3Trunc(Replicated3PC):
@@ -63,25 +64,30 @@ class ABY3Trunc(Replicated3PC):
             return super().trunc(x, key, shift=shift)
         out_fb = x.fb - shift
         n = numel(x.shape)
-        kr, k1, k2 = jax.random.split(key, 3)
-        utype = jnp.uint32 if ring.bits == 32 else jnp.uint64
-        # r from the "safe" range [0, 2**(bits-2)) to avoid sign wrap
-        r = (ring.rand(kr, x.shape).astype(utype) >> 2).astype(ring.dtype)
-        r_t = r >> shift
-        rsh = self.share_encoded(k1, r, ring)
-        rtsh = self.share_encoded(k2, r_t, ring)
-        masked = x.sh + rsh
-        # wire payload, phased like the protocol: sub-round 0 is the
-        # pair-generation resharing (one component per party), sub-round
-        # 1 the dependent masked open (neighbour sends the component
-        # party i lacks) — 2 phases x 3 messages = the priced 6 tensors
-        comm.record("trunc2", rounds=2, nbytes=6 * ring.elem_bytes * n,
-                    numel=n, tag="bw",
-                    payload=[(i, (i - 1) % 3, rsh[i], 0)
-                             for i in range(3)]
-                    + [((i + 1) % 3, i, masked[(i + 2) % 3], 1)
-                       for i in range(3)])
-        m = masked[0] + masked[1] + masked[2]        # open x + r
-        m_t = m >> shift                              # public exact shift
-        out = jnp.stack([m_t - rtsh[0], -rtsh[1], -rtsh[2]])
-        return x.with_scale(out, out_fb)
+        with span("mpc.trunc"):
+            kr, k1, k2 = jax.random.split(key, 3)
+            utype = jnp.uint32 if ring.bits == 32 else jnp.uint64
+            # r from the "safe" range [0, 2**(bits-2)) to avoid sign wrap
+            r = (ring.rand(kr, x.shape).astype(utype) >> 2).astype(
+                ring.dtype)
+            r_t = r >> shift
+            rsh = self.share_encoded(k1, r, ring)
+            rtsh = self.share_encoded(k2, r_t, ring)
+            with span("mpc.open"):
+                masked = x.sh + rsh
+                # wire payload, phased like the protocol: sub-round 0 is
+                # the pair-generation resharing (one component per
+                # party), sub-round 1 the dependent masked open
+                # (neighbour sends the component party i lacks) — 2
+                # phases x 3 messages = the priced 6 tensors
+                comm.record("trunc2", rounds=2,
+                            nbytes=6 * ring.elem_bytes * n, numel=n,
+                            tag="bw",
+                            payload=[(i, (i - 1) % 3, rsh[i], 0)
+                                     for i in range(3)]
+                            + [((i + 1) % 3, i, masked[(i + 2) % 3], 1)
+                               for i in range(3)])
+                m = masked[0] + masked[1] + masked[2]    # open x + r
+            m_t = m >> shift                          # public exact shift
+            out = jnp.stack([m_t - rtsh[0], -rtsh[1], -rtsh[2]])
+            return x.with_scale(out, out_fb)

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.mpc.ring import RingSpec
 from repro.mpc import comm
 from repro.mpc.protocols.base import BackendDefaults, numel
+from repro.spans import span
 
 
 def _share_raw(key: jax.Array, enc: jax.Array, ring: RingSpec) -> jax.Array:
@@ -51,29 +52,31 @@ def _record_offline(op: str, ring: RingSpec, n_elems: int) -> None:
 def mul_triple(key: jax.Array, shape, ring: RingSpec):
     """Elementwise triple: a*b = c (c at 2*frac scale — consumed pre-trunc)."""
     from repro.mpc.sharing import Share
-    ka, kb, k1, k2, k3 = jax.random.split(key, 5)
-    a = ring.rand(ka, shape)
-    b = ring.rand(kb, shape)
-    c = a * b   # ring product, wraps mod 2**bits
-    _record_offline("offline.mul_triple", ring, 3 * numel(shape))
-    return (Share(_share_raw(k1, a, ring), ring),
-            Share(_share_raw(k2, b, ring), ring),
-            Share(_share_raw(k3, c, ring), ring))
+    with span("mpc.dealer"):
+        ka, kb, k1, k2, k3 = jax.random.split(key, 5)
+        a = ring.rand(ka, shape)
+        b = ring.rand(kb, shape)
+        c = a * b   # ring product, wraps mod 2**bits
+        _record_offline("offline.mul_triple", ring, 3 * numel(shape))
+        return (Share(_share_raw(k1, a, ring), ring),
+                Share(_share_raw(k2, b, ring), ring),
+                Share(_share_raw(k3, c, ring), ring))
 
 
 def matmul_triple(key: jax.Array, a_shape, b_shape, ring: RingSpec,
                   dimension_numbers=None):
     """Matrix triple A@B = C for arbitrary batched matmul shapes."""
     from repro.mpc.sharing import Share
-    ka, kb, k1, k2, k3 = jax.random.split(key, 5)
-    a = ring.rand(ka, a_shape)
-    b = ring.rand(kb, b_shape)
-    c = jnp.matmul(a, b, preferred_element_type=ring.dtype)
-    _record_offline("offline.matmul_triple", ring,
-                    numel(a_shape) + numel(b_shape) + numel(c.shape))
-    return (Share(_share_raw(k1, a, ring), ring),
-            Share(_share_raw(k2, b, ring), ring),
-            Share(_share_raw(k3, c, ring), ring))
+    with span("mpc.dealer"):
+        ka, kb, k1, k2, k3 = jax.random.split(key, 5)
+        a = ring.rand(ka, a_shape)
+        b = ring.rand(kb, b_shape)
+        c = jnp.matmul(a, b, preferred_element_type=ring.dtype)
+        _record_offline("offline.matmul_triple", ring,
+                        numel(a_shape) + numel(b_shape) + numel(c.shape))
+        return (Share(_share_raw(k1, a, ring), ring),
+                Share(_share_raw(k2, b, ring), ring),
+                Share(_share_raw(k3, c, ring), ring))
 
 
 def trunc_pair(key: jax.Array, shape, ring: RingSpec,
@@ -88,14 +91,16 @@ def trunc_pair(key: jax.Array, shape, ring: RingSpec,
     """
     from repro.mpc.sharing import Share
     shift = ring.frac_bits if shift is None else shift
-    kr, k1, k2 = jax.random.split(key, 3)
-    # r drawn from the "safe" range [0, 2**(bits-2)) to avoid sign wrap
-    r = (ring.rand(kr, shape).astype(jnp.uint32 if ring.bits == 32 else jnp.uint64)
-         >> 2).astype(ring.dtype)
-    r_t = r >> shift             # arithmetic shift of non-negative r
-    _record_offline("offline.trunc_pair", ring, 2 * numel(shape))
-    return (Share(_share_raw(k1, r, ring), ring),
-            Share(_share_raw(k2, r_t, ring), ring))
+    with span("mpc.dealer"):
+        kr, k1, k2 = jax.random.split(key, 3)
+        # r drawn from the "safe" range [0, 2**(bits-2)) to avoid sign wrap
+        r = (ring.rand(kr, shape).astype(jnp.uint32 if ring.bits == 32
+                                         else jnp.uint64)
+             >> 2).astype(ring.dtype)
+        r_t = r >> shift             # arithmetic shift of non-negative r
+        _record_offline("offline.trunc_pair", ring, 2 * numel(shape))
+        return (Share(_share_raw(k1, r, ring), ring),
+                Share(_share_raw(k2, r_t, ring), ring))
 
 
 def triple_bytes(a_shape, b_shape, c_shape, ring: RingSpec) -> int:
@@ -142,12 +147,14 @@ class Additive2PC(BackendDefaults):
         of every tensor, routed to the peer — what `--wire` runs
         serialize onto the real transport (comm.WireTape).
         """
-        wire_elems = sum(numel(t.shape[1:]) for t in tensors)
-        comm.record(op, rounds=1, nbytes=2 * ring.elem_bytes * wire_elems,
-                    numel=n, flops=flops, tag="bw",
-                    payload=[(p, 1 - p, t[p])
-                             for t in tensors for p in (0, 1)])
-        return tuple(t[0] + t[1] for t in tensors)
+        with span("mpc.open"):
+            wire_elems = sum(numel(t.shape[1:]) for t in tensors)
+            comm.record(op, rounds=1,
+                        nbytes=2 * ring.elem_bytes * wire_elems,
+                        numel=n, flops=flops, tag="bw",
+                        payload=[(p, 1 - p, t[p])
+                                 for t in tensors for p in (0, 1)])
+            return tuple(t[0] + t[1] for t in tensors)
 
     # -- truncation -----------------------------------------------------
     def trunc(self, x, key: jax.Array | None, *, shift: int | None = None):
@@ -169,16 +176,18 @@ class Additive2PC(BackendDefaults):
             s1 = -((-x.sh[1]) >> shift)
             return x.with_scale(jnp.stack([s0, s1]), out_fb)
         # dealer-assisted exact truncation (TPU ring)
-        r, r_t = trunc_pair(key, x.shape, ring, shift)
-        masked = x.sh + r.sh
-        m = masked[0] + masked[1]                # open
-        comm.record("trunc_open", rounds=1,
-                    nbytes=2 * ring.elem_bytes * numel(x.shape),
-                    numel=numel(x.shape), tag="bw",
-                    payload=[(0, 1, masked[0]), (1, 0, masked[1])])
-        m_t = m >> shift
-        pub = jnp.stack([m_t, jnp.zeros_like(m_t)])
-        return x.with_scale(pub - r_t.sh, out_fb)
+        with span("mpc.trunc"):
+            r, r_t = trunc_pair(key, x.shape, ring, shift)
+            with span("mpc.open"):
+                masked = x.sh + r.sh
+                m = masked[0] + masked[1]
+                comm.record("trunc_open", rounds=1,
+                            nbytes=2 * ring.elem_bytes * numel(x.shape),
+                            numel=numel(x.shape), tag="bw",
+                            payload=[(0, 1, masked[0]), (1, 0, masked[1])])
+            m_t = m >> shift
+            pub = jnp.stack([m_t, jnp.zeros_like(m_t)])
+            return x.with_scale(pub - r_t.sh, out_fb)
 
     # -- multiplication -------------------------------------------------
     def mul(self, x, y, key: jax.Array):
@@ -194,9 +203,10 @@ class Additive2PC(BackendDefaults):
         n = numel(shape)
         eps_o, dlt_o = self._open_flight("beaver_mul", (eps, dlt), ring,
                                          n=n, flops=4 * n)
-        z = c.sh + eps_o * b.sh + dlt_o * a.sh
-        z = z.at[0].add(eps_o * dlt_o)
-        return x.with_sh(z)
+        with span("mpc.combine"):
+            z = c.sh + eps_o * b.sh + dlt_o * a.sh
+            z = z.at[0].add(eps_o * dlt_o)
+            return x.with_sh(z)
 
     def matmul(self, x, y, key: jax.Array, *,
                combine_impl: str | None = None):
@@ -230,16 +240,16 @@ class Additive2PC(BackendDefaults):
         # matmuls) flatten their batch dims into rows: row-wise int32
         # ring arithmetic is exact, so the flattened combine is bitwise
         # identical to the per-batch inline one.
-        if combine_impl is not None and ring.bits == 32 \
-                and y.sh.ndim == 3 and x.sh.ndim >= 3:
-            from repro.kernels import ops as kops
-            eps2 = eps_o.reshape(-1, k)
-            z = kops.secure_matmul(eps2, dlt_o,
-                                   a.sh.reshape(2, -1, k), b.sh,
-                                   c.sh.reshape(2, -1, n_out),
-                                   impl=combine_impl)
-            out = x.with_sh(z.reshape(c.sh.shape))
-        else:
+        with span("mpc.combine"):
+            if combine_impl is not None and ring.bits == 32 \
+                    and y.sh.ndim == 3 and x.sh.ndim >= 3:
+                from repro.kernels import ops as kops
+                eps2 = eps_o.reshape(-1, k)
+                z = kops.secure_matmul(eps2, dlt_o,
+                                       a.sh.reshape(2, -1, k), b.sh,
+                                       c.sh.reshape(2, -1, n_out),
+                                       impl=combine_impl)
+                return x.with_sh(z.reshape(c.sh.shape))
             eb = jnp.matmul(jnp.stack([eps_o, eps_o]), b.sh,
                             preferred_element_type=ring.dtype)
             ad = jnp.matmul(a.sh, jnp.stack([dlt_o, dlt_o]),
@@ -247,5 +257,4 @@ class Additive2PC(BackendDefaults):
             z = c.sh + eb + ad
             ed = jnp.matmul(eps_o, dlt_o, preferred_element_type=ring.dtype)
             z = z.at[0].add(ed)
-            out = x.with_sh(z)
-        return out
+            return x.with_sh(z)

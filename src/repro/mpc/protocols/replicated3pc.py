@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from repro.mpc.ring import RingSpec
 from repro.mpc import comm
 from repro.mpc.protocols.base import BackendDefaults, numel
+from repro.spans import span
 
 
 class Replicated3PC(BackendDefaults):
@@ -112,13 +113,15 @@ class Replicated3PC(BackendDefaults):
         if key is None:
             return x.with_scale(jnp.stack([hi, jnp.zeros_like(hi), lo]),
                                 out_fb)
-        r = ring.rand(key, hi.shape)
-        n = numel(x.shape)
-        # the re-replication message: party 1's fresh component r reaches
-        # party 0 to restore the 2-of-3 pair invariant
-        comm.record("trunc_reshare", rounds=0, nbytes=ring.elem_bytes * n,
-                    numel=n, tag="bw", payload=[(1, 0, r)])
-        return x.with_scale(jnp.stack([hi - r, r, lo]), out_fb)
+        with span("mpc.trunc"):
+            r = ring.rand(key, hi.shape)
+            n = numel(x.shape)
+            # the re-replication message: party 1's fresh component r
+            # reaches party 0 to restore the 2-of-3 pair invariant
+            comm.record("trunc_reshare", rounds=0,
+                        nbytes=ring.elem_bytes * n, numel=n, tag="bw",
+                        payload=[(1, 0, r)])
+            return x.with_scale(jnp.stack([hi - r, r, lo]), out_fb)
 
     # -- multiplication -------------------------------------------------
     def _cross_terms(self, xs: jax.Array, ys: jax.Array, key: jax.Array,
@@ -139,15 +142,17 @@ class Replicated3PC(BackendDefaults):
         lives in `mpc/ops.py`."""
         ring = x.ring
         shape = jnp.broadcast_shapes(x.shape, y.shape)
-        xb = jnp.broadcast_to(x.sh, (3,) + shape)
-        yb = jnp.broadcast_to(y.sh, (3,) + shape)
-        z = self._cross_terms(xb, yb, jax.random.fold_in(key, 1), ring,
-                              mm=False)
-        n = numel(shape)
-        comm.record("reshare_mul", rounds=1, nbytes=3 * ring.elem_bytes * n,
-                    numel=n, flops=6 * n, tag="bw",
-                    payload=[(i, (i - 1) % 3, z[i]) for i in range(3)])
-        return x.with_sh(z)
+        with span("mpc.reshare"):
+            xb = jnp.broadcast_to(x.sh, (3,) + shape)
+            yb = jnp.broadcast_to(y.sh, (3,) + shape)
+            z = self._cross_terms(xb, yb, jax.random.fold_in(key, 1), ring,
+                                  mm=False)
+            n = numel(shape)
+            comm.record("reshare_mul", rounds=1,
+                        nbytes=3 * ring.elem_bytes * n, numel=n,
+                        flops=6 * n, tag="bw",
+                        payload=[(i, (i - 1) % 3, z[i]) for i in range(3)])
+            return x.with_sh(z)
 
     def matmul(self, x, y, key: jax.Array, *,
                combine_impl: str | None = None):
@@ -155,14 +160,15 @@ class Replicated3PC(BackendDefaults):
         flight of the OUTPUT (bytes ~ batch*m*n, vs 2PC's |x|+|y|).
         `combine_impl` is a 2PC Beaver-combine knob and is ignored."""
         ring = x.ring
-        z = self._cross_terms(x.sh, y.sh, jax.random.fold_in(key, 1), ring,
-                              mm=True)
-        m, k = x.shape[-2], x.shape[-1]
-        n_out = y.shape[-1]
-        batch = numel(z.shape[1:-2])
-        n = batch * m * n_out
-        comm.record("reshare_matmul", rounds=1,
-                    nbytes=3 * ring.elem_bytes * n, numel=n,
-                    flops=6 * batch * m * k * n_out, tag="bw",
-                    payload=[(i, (i - 1) % 3, z[i]) for i in range(3)])
-        return x.with_sh(z)
+        with span("mpc.reshare"):
+            z = self._cross_terms(x.sh, y.sh, jax.random.fold_in(key, 1),
+                                  ring, mm=True)
+            m, k = x.shape[-2], x.shape[-1]
+            n_out = y.shape[-1]
+            batch = numel(z.shape[1:-2])
+            n = batch * m * n_out
+            comm.record("reshare_matmul", rounds=1,
+                        nbytes=3 * ring.elem_bytes * n, numel=n,
+                        flops=6 * batch * m * k * n_out, tag="bw",
+                        payload=[(i, (i - 1) % 3, z[i]) for i in range(3)])
+            return x.with_sh(z)

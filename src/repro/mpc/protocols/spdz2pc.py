@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from repro.mpc.ring import RingSpec, RING32, RING64
 from repro.mpc import comm
 from repro.mpc.protocols.base import BackendDefaults, numel
+from repro.spans import span
 
 _MAC_SEED = 0xA1C
 
@@ -196,24 +197,37 @@ def _record_offline_mac(op: str, ring: RingSpec, n_elems: int) -> None:
 
 
 def _mac_mul_triple(key: jax.Array, shape, ring: RingSpec):
-    ka, kb, k1, k2, k3 = jax.random.split(key, 5)
-    a = ring.rand(ka, shape)
-    b = ring.rand(kb, shape)
-    c = a * b
-    _record_offline_mac("offline.mul_triple", ring, 3 * numel(shape))
-    return (_share_mac(k1, a, ring), _share_mac(k2, b, ring),
-            _share_mac(k3, c, ring))
+    with span("mpc.dealer"):
+        ka, kb, k1, k2, k3 = jax.random.split(key, 5)
+        a = ring.rand(ka, shape)
+        b = ring.rand(kb, shape)
+        c = a * b
+        _record_offline_mac("offline.mul_triple", ring, 3 * numel(shape))
+        return (_share_mac(k1, a, ring), _share_mac(k2, b, ring),
+                _share_mac(k3, c, ring))
 
 
 def _mac_matmul_triple(key: jax.Array, a_shape, b_shape, ring: RingSpec):
-    ka, kb, k1, k2, k3 = jax.random.split(key, 5)
-    a = ring.rand(ka, a_shape)
-    b = ring.rand(kb, b_shape)
-    c = jnp.matmul(a, b, preferred_element_type=ring.dtype)
-    _record_offline_mac("offline.matmul_triple", ring,
-                        numel(a_shape) + numel(b_shape) + numel(c.shape))
-    return (_share_mac(k1, a, ring), _share_mac(k2, b, ring),
-            _share_mac(k3, c, ring))
+    with span("mpc.dealer"):
+        ka, kb, k1, k2, k3 = jax.random.split(key, 5)
+        a = ring.rand(ka, a_shape)
+        b = ring.rand(kb, b_shape)
+        c = jnp.matmul(a, b, preferred_element_type=ring.dtype)
+        _record_offline_mac("offline.matmul_triple", ring,
+                            numel(a_shape) + numel(b_shape) + numel(c.shape))
+        return (_share_mac(k1, a, ring), _share_mac(k2, b, ring),
+                _share_mac(k3, c, ring))
+
+
+def _mac_trunc_pair(key: jax.Array, shape, ring: RingSpec, shift: int):
+    """The dealer's MAC'd (r, r >> shift), r from the "safe" range
+    [0, 2**(bits-2)) to avoid sign wrap."""
+    with span("mpc.dealer"):
+        kr, k1, k2 = jax.random.split(key, 3)
+        utype = jnp.uint32 if ring.bits == 32 else jnp.uint64
+        r = (ring.rand(kr, shape).astype(utype) >> 2).astype(ring.dtype)
+        _record_offline_mac("offline.trunc_pair", ring, 2 * numel(shape))
+        return _share_mac(k1, r, ring), _share_mac(k2, r >> shift, ring)
 
 
 # ---------------------------------------------------------------------------
@@ -269,18 +283,20 @@ class SPDZ2PC(BackendDefaults):
         """Partially open masked tensors in ONE flight (value components
         only — same wire bytes as semi-honest 2PC) and enqueue each
         tensor's MAC obligation for the batched boundary check."""
-        wire_elems = sum(numel(t.shape[1:]) for t in tensors)
-        comm.record(op, rounds=1, nbytes=2 * ring.elem_bytes * wire_elems,
-                    numel=n, flops=flops, tag="bw",
-                    payload=[(p, 1 - p, t[p])
-                             for t in tensors for p in (0, 1)])
-        out = []
-        for t in tensors:
-            t = _maybe_tamper(t)
-            v = t[0] + t[1]
-            _note_open(op, v, t[2] + t[3], ring)
-            out.append(v)
-        return tuple(out)
+        with span("mpc.open"):
+            wire_elems = sum(numel(t.shape[1:]) for t in tensors)
+            comm.record(op, rounds=1,
+                        nbytes=2 * ring.elem_bytes * wire_elems,
+                        numel=n, flops=flops, tag="bw",
+                        payload=[(p, 1 - p, t[p])
+                                 for t in tensors for p in (0, 1)])
+            out = []
+            for t in tensors:
+                t = _maybe_tamper(t)
+                v = t[0] + t[1]
+                _note_open(op, v, t[2] + t[3], ring)
+                out.append(v)
+            return tuple(out)
 
     # -- truncation -----------------------------------------------------
     def trunc(self, x, key: jax.Array | None, *, shift: int | None = None):
@@ -303,20 +319,14 @@ class SPDZ2PC(BackendDefaults):
         shift = ring.frac_bits if shift is None else shift
         out_fb = x.fb - shift
         n = numel(x.shape)
-        kr, k1, k2 = jax.random.split(key, 3)
-        utype = jnp.uint32 if ring.bits == 32 else jnp.uint64
-        # r from the "safe" range [0, 2**(bits-2)) to avoid sign wrap
-        r = (ring.rand(kr, x.shape).astype(utype) >> 2).astype(ring.dtype)
-        r_t = r >> shift
-        rsh = _share_mac(k1, r, ring)
-        rtsh = _share_mac(k2, r_t, ring)
-        _record_offline_mac("offline.trunc_pair", ring, 2 * n)
-        (m,) = self._open_flight("trunc_open", (x.sh + rsh,), ring, n=n)
-        m_t = m >> shift
-        _, a0, a1 = mac_key(ring)
-        out = jnp.stack([m_t - rtsh[0], -rtsh[1],
-                         a0 * m_t - rtsh[2], a1 * m_t - rtsh[3]])
-        return x.with_scale(out, out_fb)
+        with span("mpc.trunc"):
+            rsh, rtsh = _mac_trunc_pair(key, x.shape, ring, shift)
+            (m,) = self._open_flight("trunc_open", (x.sh + rsh,), ring, n=n)
+            m_t = m >> shift
+            _, a0, a1 = mac_key(ring)
+            out = jnp.stack([m_t - rtsh[0], -rtsh[1],
+                             a0 * m_t - rtsh[2], a1 * m_t - rtsh[3]])
+            return x.with_scale(out, out_fb)
 
     # -- multiplication -------------------------------------------------
     def _sacrifice(self, op: str, ring: RingSpec, n_triple: int,
@@ -343,13 +353,14 @@ class SPDZ2PC(BackendDefaults):
         self._sacrifice("triple", ring, 3 * n, n, 2 * n)
         eps, dlt = self._open_flight("beaver_mul", (xb - a4, yb - b4), ring,
                                      n=n, flops=4 * n)
-        _, a0, a1 = mac_key(ring)
-        ed = eps * dlt
-        z = c4 + eps * b4 + dlt * a4
-        z = z.at[0].add(ed)
-        z = z.at[2].add(a0 * ed)
-        z = z.at[3].add(a1 * ed)
-        return x.with_sh(z)
+        with span("mpc.combine"):
+            _, a0, a1 = mac_key(ring)
+            ed = eps * dlt
+            z = c4 + eps * b4 + dlt * a4
+            z = z.at[0].add(ed)
+            z = z.at[2].add(a0 * ed)
+            z = z.at[3].add(a1 * ed)
+            return x.with_sh(z)
 
     def matmul(self, x, y, key: jax.Array, *,
                combine_impl: str | None = None):
@@ -370,17 +381,18 @@ class SPDZ2PC(BackendDefaults):
                                      (x.sh - a4, y.sh - b4), ring,
                                      n=na + nb,
                                      flops=2 * batch * m * k * n_out)
-        eb = jnp.matmul(jnp.broadcast_to(eps, (4,) + eps.shape), b4,
-                        preferred_element_type=ring.dtype)
-        ad = jnp.matmul(a4, jnp.broadcast_to(dlt, (4,) + dlt.shape),
-                        preferred_element_type=ring.dtype)
-        z = c4 + eb + ad
-        ed = jnp.matmul(eps, dlt, preferred_element_type=ring.dtype)
-        _, a0, a1 = mac_key(ring)
-        z = z.at[0].add(ed)
-        z = z.at[2].add(a0 * ed)
-        z = z.at[3].add(a1 * ed)
-        return x.with_sh(z)
+        with span("mpc.combine"):
+            eb = jnp.matmul(jnp.broadcast_to(eps, (4,) + eps.shape), b4,
+                            preferred_element_type=ring.dtype)
+            ad = jnp.matmul(a4, jnp.broadcast_to(dlt, (4,) + dlt.shape),
+                            preferred_element_type=ring.dtype)
+            z = c4 + eb + ad
+            ed = jnp.matmul(eps, dlt, preferred_element_type=ring.dtype)
+            _, a0, a1 = mac_key(ring)
+            z = z.at[0].add(ed)
+            z = z.at[2].add(a0 * ed)
+            z = z.at[3].add(a1 * ed)
+            return x.with_sh(z)
 
     # -- the boundary check ---------------------------------------------
     def mac_check_flight(self, ring: RingSpec) -> None:

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.mpc.ring import RingSpec, RING64
 from repro.mpc import comm
+from repro.spans import span
 
 
 @jax.tree_util.register_pytree_node_class
@@ -156,8 +157,9 @@ def share_encoded(key: jax.Array, enc: jax.Array, ring: RingSpec = RING64,
     encoding carries (comparison bits are shared at fb=0, making the
     b*(x-y) selection multiply exact and truncation-free)."""
     from repro.mpc import protocols
-    return Share(protocols.get(proto).share_encoded(key, enc, ring), ring,
-                 proto, fb)
+    with span("mpc.share"):
+        return Share(protocols.get(proto).share_encoded(key, enc, ring),
+                     ring, proto, fb)
 
 
 def open_(x: Share, op: str = "open") -> jax.Array:

@@ -556,6 +556,10 @@ class PartyRuntime:
             survivors = [p for p in range(n) if p != d.dead]
             plan = (self.fault_plan.without_crash()
                     if self.fault_plan is not None else None)
+            # the first attempt's survivors may still be committing
+            # cursors into run_dir: the replay keeps its own
+            if self.run_dir:
+                self.run_dir = os.path.join(self.run_dir, "degraded")
             rep = self._execute(filter_tape(self.tape, d.dead),
                                 active=survivors, fault_plan=plan)
             rep.degraded = True

@@ -231,7 +231,7 @@ class TestMeshExecution:
                 f"mesh={mode} changed entropy scores"
             assert rep.agrees(), f"mesh={mode} broke ledger agreement"
             assert dev.placement == mode
-            assert dev.device_makespan_s > 0.0
+            assert dev.waves_wall_s > 0.0
             assert dev.combine_kernel > 0, \
                 f"mesh={mode}: combines never hit the kernel"
             assert dev.combine_ref == 0
