@@ -54,6 +54,7 @@ from repro.engine.base import FULL_VARIANT
 from repro.spans import span
 from repro.mpc import comm, fusion, protocols
 from repro.mpc.comm import DeviceReport, Ledger, NetProfile, WaveTiming
+from repro.mpc.protocols import additive2pc
 from repro.mpc.ring import RING64, RingSpec, x64_scope
 from repro.mpc.sharing import AShare, share
 from repro.parallel import sharding
@@ -343,9 +344,11 @@ class PhaseRun:
                 # weights resident once per phase: each party's share
                 # components on its pod slice, value dims replicated
                 self.pp_sh = sharding.place_party_tree(self.pp_sh)
-        # the kernel-path witness counts this phase's forwards only, not
-        # the probe above (a cold probe traces every combine too)
+        # the kernel-path and dealer witnesses count this phase's
+        # forwards only, not the probe above (a cold probe traces every
+        # combine and draw too)
         self.smm0 = kops.smm_stats()
+        self.dealer0 = additive2pc.dealer_stats()
         self.t0 = time.perf_counter()
 
     def _ctx(self):
@@ -484,6 +487,9 @@ class PhaseRun:
         dev.combine_kernel = smm1["kernel"] - self.smm0["kernel"]
         dev.combine_ref = smm1["ref"] - self.smm0["ref"]
         dev.combine_padded = smm1["padded"] - self.smm0["padded"]
+        dealer1 = additive2pc.dealer_stats()
+        dev.dealer_draws = dealer1["draws"] - self.dealer0["draws"]
+        dev.dealer_traces = dealer1["traces"] - self.dealer0["traces"]
         wire_rep = None
         if self.tape is not None:
             # replay the captured flight plan as real parties: reconcile

@@ -178,7 +178,10 @@ class DeviceReport:
     jax.shard_map, party replicated per device). The combine_* counters
     are the kernels/ops.secure_matmul dispatch deltas over the phase —
     the witness that fused RING32 combines ran through the kernel
-    rather than the jnp ref fallback."""
+    rather than the jnp ref fallback. The dealer_* counters are the
+    additive2pc dealer's deltas over the phase: draws made, and traces
+    of its jitted draws (0 once every draw shape of the phase is
+    cached)."""
     placement: str
     n_devices: int
     mesh_axes: dict
@@ -186,6 +189,8 @@ class DeviceReport:
     combine_kernel: int = 0
     combine_ref: int = 0
     combine_padded: int = 0
+    dealer_draws: int = 0
+    dealer_traces: int = 0
 
     @property
     def waves_wall_s(self) -> float:
@@ -205,6 +210,8 @@ class DeviceReport:
             "combine_kernel": self.combine_kernel,
             "combine_ref": self.combine_ref,
             "combine_padded": self.combine_padded,
+            "dealer_draws": self.dealer_draws,
+            "dealer_traces": self.dealer_traces,
             "waves": [dataclasses.asdict(w) for w in self.waves],
         }
 

@@ -24,6 +24,8 @@ apply after the fact.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -47,36 +49,88 @@ def _record_offline(op: str, ring: RingSpec, n_elems: int) -> None:
 
 # ---------------------------------------------------------------------------
 # the dealer (re-exported by mpc/beaver.py for back-compat)
+#
+# Each draw is a module-level jitted core, static in shapes, ring and
+# shift, under a wrapper that records the offline bytes OUTSIDE the jit:
+# one record per draw, not per trace, also under an outer trace (the
+# probe's eval_shape, the shardmap wave's jit). The material depends
+# only on the key and the shape, so the compiled draw is bitwise the
+# eager one, and a warm wave dispatches one cached program per draw
+# instead of ~40 eager ops.
 # ---------------------------------------------------------------------------
+
+# `draws` counts wrapper calls, `traces` runs of a core's Python body,
+# which happen only when jit traces it: the executor snapshots both
+# around a phase to witness that a warm phase draws from cached programs
+_dealer_stats = {"draws": 0, "traces": 0}
+
+
+def dealer_stats() -> dict:
+    """Snapshot of the dealer's draw and trace counters."""
+    return dict(_dealer_stats)
+
+
+def reset_dealer_stats() -> None:
+    for k in _dealer_stats:
+        _dealer_stats[k] = 0
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _mul_triple_raw(key, shape, ring):
+    _dealer_stats["traces"] += 1
+    ka, kb, k1, k2, k3 = jax.random.split(key, 5)
+    a = ring.rand(ka, shape)
+    b = ring.rand(kb, shape)
+    c = a * b   # ring product, wraps mod 2**bits
+    return (_share_raw(k1, a, ring), _share_raw(k2, b, ring),
+            _share_raw(k3, c, ring))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _matmul_triple_raw(key, a_shape, b_shape, ring):
+    _dealer_stats["traces"] += 1
+    ka, kb, k1, k2, k3 = jax.random.split(key, 5)
+    a = ring.rand(ka, a_shape)
+    b = ring.rand(kb, b_shape)
+    c = jnp.matmul(a, b, preferred_element_type=ring.dtype)
+    return (_share_raw(k1, a, ring), _share_raw(k2, b, ring),
+            _share_raw(k3, c, ring))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _trunc_pair_raw(key, shape, ring, shift):
+    _dealer_stats["traces"] += 1
+    kr, k1, k2 = jax.random.split(key, 3)
+    # r drawn from the "safe" range [0, 2**(bits-2)) to avoid sign wrap
+    r = (ring.rand(kr, shape).astype(jnp.uint32 if ring.bits == 32
+                                     else jnp.uint64)
+         >> 2).astype(ring.dtype)
+    r_t = r >> shift             # arithmetic shift of non-negative r
+    return _share_raw(k1, r, ring), _share_raw(k2, r_t, ring)
+
+
+def _shares(op: str, ring: RingSpec, raw) -> tuple:
+    """A draw's host half: its offline record, its count, its Shares.
+    The record counts every element the dealer ships (both parties)."""
+    from repro.mpc.sharing import Share
+    _record_offline(op, ring, sum(numel(r.shape[1:]) for r in raw))
+    _dealer_stats["draws"] += 1
+    return tuple(Share(r, ring) for r in raw)
+
 
 def mul_triple(key: jax.Array, shape, ring: RingSpec):
     """Elementwise triple: a*b = c (c at 2*frac scale — consumed pre-trunc)."""
-    from repro.mpc.sharing import Share
     with span("mpc.dealer"):
-        ka, kb, k1, k2, k3 = jax.random.split(key, 5)
-        a = ring.rand(ka, shape)
-        b = ring.rand(kb, shape)
-        c = a * b   # ring product, wraps mod 2**bits
-        _record_offline("offline.mul_triple", ring, 3 * numel(shape))
-        return (Share(_share_raw(k1, a, ring), ring),
-                Share(_share_raw(k2, b, ring), ring),
-                Share(_share_raw(k3, c, ring), ring))
+        return _shares("offline.mul_triple", ring,
+                       _mul_triple_raw(key, tuple(shape), ring))
 
 
-def matmul_triple(key: jax.Array, a_shape, b_shape, ring: RingSpec,
-                  dimension_numbers=None):
+def matmul_triple(key: jax.Array, a_shape, b_shape, ring: RingSpec):
     """Matrix triple A@B = C for arbitrary batched matmul shapes."""
-    from repro.mpc.sharing import Share
     with span("mpc.dealer"):
-        ka, kb, k1, k2, k3 = jax.random.split(key, 5)
-        a = ring.rand(ka, a_shape)
-        b = ring.rand(kb, b_shape)
-        c = jnp.matmul(a, b, preferred_element_type=ring.dtype)
-        _record_offline("offline.matmul_triple", ring,
-                        numel(a_shape) + numel(b_shape) + numel(c.shape))
-        return (Share(_share_raw(k1, a, ring), ring),
-                Share(_share_raw(k2, b, ring), ring),
-                Share(_share_raw(k3, c, ring), ring))
+        return _shares("offline.matmul_triple", ring,
+                       _matmul_triple_raw(key, tuple(a_shape),
+                                          tuple(b_shape), ring))
 
 
 def trunc_pair(key: jax.Array, shape, ring: RingSpec,
@@ -89,18 +143,10 @@ def trunc_pair(key: jax.Array, shape, ring: RingSpec,
     whole accumulated excess (e.g. f+5 after a folded mean) so ONE pair
     clears what eager mode paid as several.
     """
-    from repro.mpc.sharing import Share
     shift = ring.frac_bits if shift is None else shift
     with span("mpc.dealer"):
-        kr, k1, k2 = jax.random.split(key, 3)
-        # r drawn from the "safe" range [0, 2**(bits-2)) to avoid sign wrap
-        r = (ring.rand(kr, shape).astype(jnp.uint32 if ring.bits == 32
-                                         else jnp.uint64)
-             >> 2).astype(ring.dtype)
-        r_t = r >> shift             # arithmetic shift of non-negative r
-        _record_offline("offline.trunc_pair", ring, 2 * numel(shape))
-        return (Share(_share_raw(k1, r, ring), ring),
-                Share(_share_raw(k2, r_t, ring), ring))
+        return _shares("offline.trunc_pair", ring,
+                       _trunc_pair_raw(key, tuple(shape), ring, shift))
 
 
 def triple_bytes(a_shape, b_shape, c_shape, ring: RingSpec) -> int:
