@@ -52,7 +52,7 @@ from repro.core.proxy import ProxySpec
 from repro.engine import MPCEngine, cached_probe, proxy_entropy
 from repro.engine.base import FULL_VARIANT
 from repro.spans import span
-from repro.mpc import comm, fusion, protocols
+from repro.mpc import comm, fusion, protocols, ops as mops
 from repro.mpc.comm import DeviceReport, Ledger, NetProfile, WaveTiming
 from repro.mpc.protocols import additive2pc
 from repro.mpc.ring import RING64, RingSpec, x64_scope
@@ -344,11 +344,12 @@ class PhaseRun:
                 # weights resident once per phase: each party's share
                 # components on its pod slice, value dims replicated
                 self.pp_sh = sharding.place_party_tree(self.pp_sh)
-        # the kernel-path and dealer witnesses count this phase's
+        # the kernel-path, dealer and reduction witnesses count this phase's
         # forwards only, not the probe above (a cold probe traces every
         # combine and draw too)
         self.smm0 = kops.smm_stats()
         self.dealer0 = additive2pc.dealer_stats()
+        self.reduce0 = mops.reduce_stats()
         self.t0 = time.perf_counter()
 
     def _ctx(self):
@@ -490,6 +491,10 @@ class PhaseRun:
         dealer1 = additive2pc.dealer_stats()
         dev.dealer_draws = dealer1["draws"] - self.dealer0["draws"]
         dev.dealer_traces = dealer1["traces"] - self.dealer0["traces"]
+        reduce1 = mops.reduce_stats()
+        dev.reduce_presum = reduce1["presum"] - self.reduce0["presum"]
+        dev.reduce_odd_recip = (reduce1["odd_recip"]
+                                - self.reduce0["odd_recip"])
         wire_rep = None
         if self.tape is not None:
             # replay the captured flight plan as real parties: reconcile

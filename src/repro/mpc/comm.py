@@ -181,7 +181,8 @@ class DeviceReport:
     rather than the jnp ref fallback. The dealer_* counters are the
     additive2pc dealer's deltas over the phase: draws made, and traces
     of its jitted draws (0 once every draw shape of the phase is
-    cached)."""
+    cached). The reduce_* counters are mpc/ops.mean's deltas: forced
+    pre-sum truncations and odd-reciprocal encodings."""
     placement: str
     n_devices: int
     mesh_axes: dict
@@ -191,6 +192,8 @@ class DeviceReport:
     combine_padded: int = 0
     dealer_draws: int = 0
     dealer_traces: int = 0
+    reduce_presum: int = 0
+    reduce_odd_recip: int = 0
 
     @property
     def waves_wall_s(self) -> float:
@@ -212,6 +215,8 @@ class DeviceReport:
             "combine_padded": self.combine_padded,
             "dealer_draws": self.dealer_draws,
             "dealer_traces": self.dealer_traces,
+            "reduce_presum": self.reduce_presum,
+            "reduce_odd_recip": self.reduce_odd_recip,
             "waves": [dataclasses.asdict(w) for w in self.waves],
         }
 

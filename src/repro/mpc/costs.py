@@ -458,6 +458,16 @@ def proxy_exec_cost(bsz: int, seq: int, d_model: int, heads: int,
             forced(v, name, v.fb - shift)
         return V(out_fb, n_out)
 
+    def mean(v: V, n: int, name: str, n_out: int) -> V:
+        """ops.mean: the pre-sum force of the reduction rule (billed at
+        the input's elements), the free 2**-k fold, then 1/m."""
+        shift = lattice.sum_plan(v.fb, n, f, ring.bits)
+        if shift:
+            forced(v, f"{name}.presum", v.fb - shift)
+        k, m = lattice.split_reciprocal(n)
+        s = V(v.fb - shift + k, n_out)
+        return s if m == 1 else mul_pub(s, 1.0 / m, f"{name}.force", n_out)
+
     def mul2(x: V, y: V, name: str, n: int) -> V:
         px, py, out_fb = lattice.mul_plan(x.fb, y.fb, f, hbits)
         if px:
@@ -489,11 +499,12 @@ def proxy_exec_cost(bsz: int, seq: int, d_model: int, heads: int,
 
     x_fb = f                          # shared activations enter canonical
     for _ in range(n_layers):
-        # MLP-LayerNorm: pow2 d folds the mean for free; the centered
-        # activation truncates ONCE (memo) though both the variance
-        # square and the normalize multiply consume it
+        # MLP-LayerNorm: each mean forces its input where the sum would
+        # outgrow the ring and folds the power of two of 1/d for free;
+        # the centered activation truncates ONCE (memo) though both the
+        # variance square and the normalize multiply consume it
         events.append(fusion.GroupBegin("ln_stats"))
-        mu = mul_pub(V(x_fb, t), 1.0 / d_model, "ln.mu.force", t)
+        mu = mean(V(x_fb, t * d_model), d_model, "ln.mu", t)
         # centering sub: exact lift unless mu's pow2 fold topped the 2f
         # cap (layer >= 2, pow2 d) — then mu down-truncs KEYED, billed
         # at its pre-broadcast rows (lineage)
@@ -502,7 +513,7 @@ def proxy_exec_cost(bsz: int, seq: int, d_model: int, heads: int,
             forced(mu, "ln.mu.align", align_fb)
         xc = V(align_fb, t * d_model)
         var_p = mul2(xc, xc, "ln.var", t * d_model)
-        var = mul_pub(V(var_p.fb, t), 1.0 / d_model, "ln.var_mean.force", t)
+        var = mean(var_p, d_model, "ln.var_mean", t)
         events.append(fusion.GROUP_END)
         inv = mlp(var, t, 1, mlp_hidden, 1, "mlp_ln")
         # normalize: inv's force bills its pre-broadcast rows (lineage)
@@ -523,8 +534,7 @@ def proxy_exec_cost(bsz: int, seq: int, d_model: int, heads: int,
         o = mm(probs, v_, "av", bsz * w, seq, seq, d_head)
         out = mm(o, W, "out", 1, t, w * d_head, d_model)
         x_fb = lattice.align_target(x_fb, out.fb, f, hbits)  # residual
-    pooled = mul_pub(V(x_fb, bsz * d_model), 1.0 / seq, "pool.force",
-                     bsz * d_model)
+    pooled = mean(V(x_fb, t * d_model), seq, "pool", bsz * d_model)
     logits = mm(pooled, W, "head", 1, bsz, d_model, classes)
     ent = mlp(logits, bsz, classes, mlp_hidden, 1, "mlp_se")
     # the engine's entropy head forces its output canonical — the

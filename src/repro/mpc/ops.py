@@ -67,7 +67,7 @@ def lift(x: Share, k: int) -> Share:
 
 
 def force(x: Share, key: jax.Array | None = None, *,
-          to: int | None = None) -> Share:
+          to: int | None = None, site: str | None = None) -> Share:
     """Resolve a scale-carrying share to exponent `to` (canonical f by
     default) — THE deferred-truncation consumer.
 
@@ -77,6 +77,7 @@ def force(x: Share, key: jax.Array | None = None, *,
     not one per consumer) and pushed through layout lineage
     (`Share.derive`): forcing a broadcast/reshaped view truncates the
     pre-layout tensor at its element count and replays the free layout.
+    `site` names the caller on the backend's `sf.mpc.trunc` span.
     """
     t = x.ring.frac_bits if to is None else to
     if x.fb == t:
@@ -91,10 +92,10 @@ def force(x: Share, key: jax.Array | None = None, *,
     lineage = getattr(x, "_lineage", None)
     if lineage is not None:
         base, fn = lineage
-        fbase = force(base, key, to=t)
+        fbase = force(base, key, to=t, site=site)
         out = fbase.with_sh(fn(fbase.sh))
     else:
-        out = x.backend.trunc(x, key, shift=x.fb - t)
+        out = x.backend.trunc(x, key, shift=x.fb - t, site=site)
     cache[t] = out
     return out
 
@@ -201,6 +202,20 @@ def matmul_public(x: Share, w, *, key: jax.Array | None = None,
     return x.with_scale(z, x.fb + x.ring.frac_bits)
 
 
+_reduce_stats = {"presum": 0, "odd_recip": 0}
+
+
+def reduce_stats() -> dict:
+    """Snapshot of `mean`'s counters: forced pre-sum truncations and
+    odd-reciprocal encodings, one per call (a vmapped wave counts once)."""
+    return dict(_reduce_stats)
+
+
+def reset_reduce_stats() -> None:
+    for k in _reduce_stats:
+        _reduce_stats[k] = 0
+
+
 def sum_(x: Share, axis=None, keepdims=False) -> Share:
     ax = axis
     if ax is not None:
@@ -212,11 +227,25 @@ def sum_(x: Share, axis=None, keepdims=False) -> Share:
 
 
 def mean(x: Share, axis: int, *, key: jax.Array | None = None) -> Share:
-    """Sum then multiply by 1/n — the 1/n lands on the (smaller) summed
-    tensor, and for power-of-two n it is a free exponent fold."""
+    """Sum then multiply by 1/n, the 1/n on the (smaller) summed tensor.
+
+    The input first forces down where the sum would outgrow the ring
+    (`scale.sum_plan`, a `site="presum"` truncation keyed apart from
+    the rest); 1/n = 2**-k * (1/m), m odd (`scale.split_reciprocal`):
+    the 2**-k folds for free, and only 1/m is encoded, by `mul_public`,
+    whose headroom plan truncates the folded sum first."""
     n = x.shape[axis]
-    s = sum_(x, axis=axis)
-    return mul_public(s, 1.0 / n, key=key)
+    shift = scale.sum_plan(x.fb, n, x.ring.frac_bits, x.ring.bits)
+    if shift:
+        _reduce_stats["presum"] += 1
+        kp = None if key is None else jax.random.fold_in(key, 60)
+        x = force(x, kp, to=x.fb - shift, site="presum")
+    k, m = scale.split_reciprocal(n)
+    s = mul_public(sum_(x, axis=axis), 2.0 ** -k)
+    if m == 1:
+        return s
+    _reduce_stats["odd_recip"] += 1
+    return mul_public(s, 1.0 / m, key=key)
 
 
 def stack(xs: list[Share], axis: int = 0, *,

@@ -51,7 +51,8 @@ class ABY3Trunc(Replicated3PC):
     # fallback is never on the executed forward path)
     exact_trunc = True
 
-    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None):
+    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None,
+              site: str | None = None):
         """Two-phase exact truncation (see module docstring). One
         `trunc2` record: rounds=2 (pair generation, then the dependent
         masked open — a batcher barrier), bytes = 2 phases x 3 messages
@@ -61,10 +62,10 @@ class ABY3Trunc(Replicated3PC):
         if key is None:
             # boundary-only fallback: probabilistic regroup (documented;
             # the engine always supplies keys on the executed path)
-            return super().trunc(x, key, shift=shift)
+            return super().trunc(x, key, shift=shift, site=site)
         out_fb = x.fb - shift
         n = numel(x.shape)
-        with span("mpc.trunc"):
+        with span("mpc.trunc", site=site):
             kr, k1, k2 = jax.random.split(key, 3)
             utype = jnp.uint32 if ring.bits == 32 else jnp.uint64
             # r from the "safe" range [0, 2**(bits-2)) to avoid sign wrap

@@ -203,7 +203,8 @@ class Additive2PC(BackendDefaults):
             return tuple(t[0] + t[1] for t in tensors)
 
     # -- truncation -----------------------------------------------------
-    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None):
+    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None,
+              site: str | None = None):
         """Divide shares by 2**shift (default: one canonical scale).
 
         RING64 (or keyless boundary trunc): local arithmetic shift of
@@ -222,7 +223,7 @@ class Additive2PC(BackendDefaults):
             s1 = -((-x.sh[1]) >> shift)
             return x.with_scale(jnp.stack([s0, s1]), out_fb)
         # dealer-assisted exact truncation (TPU ring)
-        with span("mpc.trunc"):
+        with span("mpc.trunc", site=site):
             r, r_t = trunc_pair(key, x.shape, ring, shift)
             with span("mpc.open"):
                 masked = x.sh + r.sh

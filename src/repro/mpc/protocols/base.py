@@ -145,8 +145,10 @@ class ProtocolBackend(Protocol):
         """Batched secure matmul (raw product; see `mul`)."""
         ...
 
-    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None):
+    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None,
+              site: str | None = None):
         """Divide by 2**shift (default: frac_bits, one canonical scale)
         and lower the carried exponent accordingly — the generalized
-        `trunc(shift=)` that resolves any accumulated excess in one op."""
+        `trunc(shift=)` that resolves any accumulated excess in one op.
+        `site` is meta on the `sf.mpc.trunc` span, where there is one."""
         ...

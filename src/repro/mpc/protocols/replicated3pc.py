@@ -89,7 +89,8 @@ class Replicated3PC(BackendDefaults):
         return r - jnp.roll(r, -1, axis=0)
 
     # -- truncation -----------------------------------------------------
-    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None):
+    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None,
+              site: str | None = None):
         """Probabilistic local truncation via the 2-of-2 regrouping —
         both rings, zero rounds, zero dealer bytes, any shift. On the
         TPU ring this trades additive2pc's exact dealer pair for a
@@ -113,7 +114,7 @@ class Replicated3PC(BackendDefaults):
         if key is None:
             return x.with_scale(jnp.stack([hi, jnp.zeros_like(hi), lo]),
                                 out_fb)
-        with span("mpc.trunc"):
+        with span("mpc.trunc", site=site):
             r = ring.rand(key, hi.shape)
             n = numel(x.shape)
             # the re-replication message: party 1's fresh component r

@@ -299,7 +299,8 @@ class SPDZ2PC(BackendDefaults):
             return tuple(out)
 
     # -- truncation -----------------------------------------------------
-    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None):
+    def trunc(self, x, key: jax.Array | None, *, shift: int | None = None,
+              site: str | None = None):
         """Dealer-assisted MAC'd truncation — BOTH rings.
 
         Local shifting is not MAC-preserving (alpha*(x >> s) has no
@@ -319,7 +320,7 @@ class SPDZ2PC(BackendDefaults):
         shift = ring.frac_bits if shift is None else shift
         out_fb = x.fb - shift
         n = numel(x.shape)
-        with span("mpc.trunc"):
+        with span("mpc.trunc", site=site):
             rsh, rtsh = _mac_trunc_pair(key, x.shape, ring, shift)
             (m,) = self._open_flight("trunc_open", (x.sh + rsh,), ring, n=n)
             m_t = m >> shift

@@ -25,6 +25,14 @@ uniform 2f cap. Pure reinterpretations (pow2 folds) may push fb beyond
 the cap because the integers never move; the next magnitude-growing
 consumer truncates by the accumulated excess in one shot.
 
+Reductions grow magnitude too: a sum of n terms at exponent s can
+reach n times the largest term, so it needs ceil(log2 n) more bits of
+headroom than its terms (`sum_plan`). Means divide by n as
+2**-k * (1/m) with m odd (`split_reciprocal`): the power of two folds
+for free and only 1/m is encoded, never a 1/n that rounds badly at f
+bits (1/768 at 12 bits is 5/4096, 6.25% low; 1/3 is 1365/4096, 0.02%
+low).
+
 This module is the decision procedure only — pure functions of static
 exponents, shared verbatim by the executable ops (`mpc/ops.py`) and the
 analytic mirror (`mpc/costs.proxy_exec_cost`), so "where does a forced
@@ -132,3 +140,55 @@ def mul_public_plan(s: int, v, f: int,
         return k, 0, s - k
     shift = max(0, s - (cap(f, bits) - f))   # product lands on the cap
     return None, shift, (s - shift) + f
+
+
+# log2 of the bound on the mean magnitude of the terms a reduction sums:
+# |sum of n terms| <= n * 2**SUM_ALLOWANCE. The program's reductions are
+# LayerNorm statistics (the mean of the residual stream and the mean of
+# its centred squares) and the pooled residual stream. Their size is set
+# by the weights, not by the embedding's scale alone: attention outputs
+# pile onto the residual stream, and on random proxies at hidden 768 the
+# pooled stream reaches about 15 where the embeddings alone give 0.55.
+# So the bound is the lattice's own: a dealer truncation draws its mask
+# from [0, 2**(bits - 2)) and is exact only on values below 2**(bits - 2)
+# (`protocols/additive2pc.trunc_pair`), so a product at the 2f cap that
+# a truncation consumes holds magnitudes below 2**(bits - 2 - 2f) = 2**6
+# on RING32, and so does a mean of such terms; the rule adds no bound of
+# its own to the ring's. A term that averages above 2**SUM_ALLOWANCE may
+# wrap the sum.
+SUM_ALLOWANCE = 6
+
+
+def sum_plan(s: int, n: int, f: int, bits: int) -> int:
+    """Shift that forces the input of an n-term sum at exponent s down
+    before the sum, on a ring of `bits` bits.
+
+    The sum of n terms averaging below 2**SUM_ALLOWANCE in magnitude,
+    encoded at s, stays below 2**(s + ceil(log2 n) + SUM_ALLOWANCE). A
+    mean's integers are its sum's, and a truncation consumes them (the
+    odd reciprocal's, or a later consumer's), so the sum must stay in
+    the truncation's range, 2**(bits - 2). Where it would not, the input
+    truncates by exactly the excess, never below canonical f: a
+    canonical input is never forced, so its sum is held to
+    2**(bits - 2 - f), 2**18 on RING32 (768 terms averaging 341). A
+    function of shapes and the ring only, never of data: RING32 (f=12)
+    forces a 2f input summed over 768 terms from 24 to 14 bits, over
+    128 terms to 17; RING64 forces nothing at 2f up to n = 2**24, and a
+    3f input over 768 terms by 2 bits."""
+    grow = (n - 1).bit_length()          # ceil(log2 n)
+    excess = s + grow + SUM_ALLOWANCE - (bits - 2)
+    return max(0, min(excess, s - f))
+
+
+def split_reciprocal(n: int) -> tuple[int, int]:
+    """(k, m) with n = 2**k * m and m odd, so 1/n = 2**-k * (1/m).
+
+    The 2**-k folds into the exponent for free (`pow2_exponent`); only
+    1/m is encoded at f bits, off by at most 2**-(f+1), a relative error
+    of at most m * 2**-(f+1) (m = 3 on RING32: 0.037%; 1365/4096 is
+    0.024% low).
+    m == 1 for a power of two: the whole mean is a fold."""
+    if n < 1:
+        raise ValueError(f"cannot split 1/{n}")
+    k = (n & -n).bit_length() - 1
+    return k, n >> k

@@ -25,7 +25,9 @@ Names, from the executor down (PERF.md lists the metric each serves):
   mpc.open, mpc.combine, mpc.trunc   the Beaver opening, the post-open
                                      combine (2pc, spdz2pc), the
                                      forced truncation (every backend;
-                                     aby3trunc's opens its masked value)
+                                     aby3trunc's opens its masked value;
+                                     meta site="presum" where
+                                     mpc/ops.mean forces a sum's input)
   mpc.reshare                        the 3-party multiply: local cross
                                      terms and the resharing flight
                                      (3pc, aby3trunc)
@@ -41,5 +43,8 @@ import jax
 
 
 def span(name: str, **meta) -> jax.profiler.TraceAnnotation:
-    """A context manager that records `sf.<name>` in a running trace."""
+    """A context manager that records `sf.<name>` in a running trace;
+    meta given as None is left out."""
+    if None in meta.values():
+        meta = {k: v for k, v in meta.items() if v is not None}
     return jax.profiler.TraceAnnotation("sf." + name, **meta)

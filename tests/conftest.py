@@ -49,6 +49,7 @@ except ImportError:
     _st = _Strategies("hypothesis.strategies")
     _hyp = types.ModuleType("hypothesis")
     _hyp.given, _hyp.settings, _hyp.strategies = _given, _settings, _st
+    _hyp.example = lambda *a, **k: (lambda fn: fn)
     _hyp.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
     sys.modules["hypothesis"] = _hyp
     sys.modules["hypothesis.strategies"] = _st

@@ -78,6 +78,59 @@ class TestLattice:
         # unequal above-cap clamps to the 2f headroom cap
         assert scale.align_target(2 * f, 2 * f + 5, f) == 2 * f
 
+    def test_sum_plan(self):
+        a = scale.SUM_ALLOWANCE
+        f, bits = RING32.frac_bits, RING32.bits
+        # a 2f product summed over 768 terms needs ceil(log2 768) = 10
+        # bits of growth: forced to bits - 2 - 10 - a, exactly
+        assert scale.sum_plan(2 * f, 768, f, bits) == 24 + 10 + a - 30
+        assert 2 * f - scale.sum_plan(2 * f, 768, f, bits) == 20 - a
+        # the excess grows with ceil(log2 n), not with n
+        assert scale.sum_plan(2 * f, 513, f, bits) \
+            == scale.sum_plan(2 * f, 1024, f, bits) \
+            == scale.sum_plan(2 * f, 768, f, bits)
+        assert scale.sum_plan(2 * f, 256, f, bits) == 24 + 8 + a - 30
+        # room to spare: no force; one term never grows
+        assert scale.sum_plan(2 * f, 2 ** (30 - 24 - a), f, bits) == 0
+        assert scale.sum_plan(2 * f, 1, f, bits) == 0
+        # never below canonical f: a canonical input is never forced
+        assert scale.sum_plan(f, 768, f, bits) == 0
+        assert scale.sum_plan(f, 2 ** 30, f, bits) == 0
+        assert scale.sum_plan(f + 3, 2 ** 30, f, bits) == 3
+        # the allowance is the dealer truncation's range at the 2f cap
+        assert a == bits - 2 - 2 * f
+        # RING64 holds a 2f sum over 2**24 terms and a 3f sum over 256;
+        # a 3f sum over 768 terms forces by the 2 bits it outgrows
+        f64 = RING64.frac_bits
+        assert scale.sum_plan(2 * f64, 2 ** 24, f64, RING64.bits) == 0
+        assert scale.sum_plan(3 * f64, 256, f64, RING64.bits) == 0
+        assert scale.sum_plan(3 * f64, 768, f64, RING64.bits) == 2
+
+    def test_split_reciprocal(self):
+        assert scale.split_reciprocal(768) == (8, 3)
+        assert scale.split_reciprocal(256) == (8, 1)
+        assert scale.split_reciprocal(1) == (0, 1)
+        assert scale.split_reciprocal(7) == (0, 7)
+        assert scale.split_reciprocal(96) == (5, 3)
+        for n in range(1, 300):
+            k, m = scale.split_reciprocal(n)
+            assert m % 2 == 1 and n == m << k
+        with pytest.raises(ValueError):
+            scale.split_reciprocal(0)
+
+    def test_odd_reciprocal_encodes_within_its_bound(self):
+        """1/m at f bits is off by at most 2**-(f+1), a relative error of
+        at most m * 2**-(f+1); 1/768 encoded whole is 6.25% low."""
+        f = RING32.frac_bits
+        enc = lambda v: round(v * 2 ** f) / 2 ** f      # noqa: E731
+        for n in (768, 384, 96, 3072, 640, 192):
+            k, m = scale.split_reciprocal(n)
+            rel = abs(enc(1 / m) * 2.0 ** -k * n - 1)
+            assert rel <= m * 2.0 ** -(f + 1), (n, rel)
+        assert enc(1 / 3) == 1365 / 4096
+        assert abs(enc(1 / 3) * 3 - 1) < 2.5e-4
+        assert abs(enc(1 / 768) * 768 - 1) == pytest.approx(0.0625)
+
 
 # ---------------------------------------------------------------------------
 # 2. scale metadata through the container
@@ -159,6 +212,95 @@ class TestPublicScalars:
                                                "trunc_open"]
         assert out.fb == 2 * RING32.frac_bits
         assert np.allclose(np.asarray(reveal(out)), [1.5, 6.0], atol=1e-2)
+
+
+class TestReductions:
+    """mops.mean under the reduction rule: at hidden 768 on RING32 a
+    2f input forces down before the sum (keyed, one dealer pair), and
+    1/768 is a free 2**-8 fold times an encoded 1/3."""
+
+    D = 768
+
+    def _x(self, i, shape=(2, 4, D)):
+        return 0.55 * jax.random.normal(_k(i), shape)
+
+    def test_mean_of_products_holds_at_hidden_768(self):
+        x = self._x(80)
+        xs = share(_k(81), x, RING32)
+        sq = mops.mul(xs, xs, _k(82))                      # 2f
+        mops.reset_reduce_stats()
+        with ledger_scope() as led:
+            var = mops.mean(sq, axis=-1, key=_k(83))
+        assert mops.reduce_stats() == {"presum": 1, "odd_recip": 1}
+        # the pre-sum pair (r, r >> shift) on every element, then the
+        # folded sum's pair on every row
+        n, rows = int(np.prod(x.shape)), int(np.prod(x.shape[:-1]))
+        assert [(r.op, r.numel) for r in led.records] == [
+            ("offline.trunc_pair", 2 * n), ("trunc_open", n),
+            ("offline.trunc_pair", 2 * rows), ("trunc_open", rows)]
+        want = np.asarray((x * x).mean(-1))
+        assert np.abs(np.asarray(reveal(var)) - want).max() < 2e-3
+
+    def _large_2f(self, i, n, level):
+        """A 2f share of rows of n terms averaging `level`: a residual
+        stream that attention outputs have grown (the pool's input)."""
+        x = level + jax.random.normal(_k(i), (2, 3, n))
+        xs = share(_k(i + 1), x, RING32)
+        one = share(_k(i + 2), jnp.ones_like(x), RING32)
+        return x, mops.mul(xs, one, _k(i + 3))              # 2f
+
+    @pytest.mark.parametrize("n", [128, 768])
+    @pytest.mark.parametrize("level", [15.0, 50.0])
+    def test_mean_holds_terms_up_to_the_lattice_bound(self, n, level):
+        """Terms averaging up to 2**SUM_ALLOWANCE = 64, the most a 2f
+        product holds where a dealer truncation consumes it on RING32,
+        sum without a wrap. Error: 2**-12-scale
+        truncation noise plus 1/m's relative encoding error, at most
+        m * 2**-(f+1) (m = 3 at n = 768)."""
+        x, x2 = self._large_2f(100, n, level)
+        got = np.asarray(reveal(mops.mean(x2, axis=-1, key=_k(104))))
+        want = np.asarray(x.mean(-1))
+        _, m = scale.split_reciprocal(n)
+        tol = 2e-3 + m * 2.0 ** -(RING32.frac_bits + 1) * np.abs(want)
+        assert (np.abs(got - want) <= tol).all(), np.abs(got - want).max()
+
+    def test_old_allowance_wraps_a_grown_pool(self, monkeypatch):
+        """With the rule's first allowance (terms below 4 against the whole
+        ring, the shifts of SUM_ALLOWANCE 1 against the truncation's
+        range) the pool of a stream averaging 15 over 128 positions
+        wraps."""
+        monkeypatch.setattr(scale, "SUM_ALLOWANCE", 1)
+        x, x2 = self._large_2f(105, 128, 15.0)
+        got = np.asarray(reveal(mops.mean(x2, axis=-1, key=_k(109))))
+        assert np.abs(got - np.asarray(x.mean(-1))).min() > 1.0
+
+    def test_canonical_input_is_never_forced(self):
+        x = self._x(84)
+        xs = share(_k(85), x, RING32)
+        mops.reset_reduce_stats()
+        mu = mops.mean(xs, axis=-1, key=_k(86))
+        assert mops.reduce_stats() == {"presum": 0, "odd_recip": 1}
+        assert np.abs(np.asarray(reveal(mu))
+                      - np.asarray(x.mean(-1))).max() < 1e-3
+
+    def test_pow2_mean_stays_a_free_fold(self):
+        x = self._x(87, (2, 4, 256))
+        xs = share(_k(88), x, RING32)
+        mops.reset_reduce_stats()
+        with ledger_scope() as led:
+            mu = mops.mean(xs, axis=-1, key=_k(89))
+        assert led.records == [] and mu.fb == RING32.frac_bits + 8
+        assert mops.reduce_stats() == {"presum": 0, "odd_recip": 0}
+
+    def test_ring64_needs_no_presum(self, x64):
+        x = self._x(90)
+        xs = share(_k(91), x)
+        sq = mops.mul(xs, xs, _k(92))
+        mops.reset_reduce_stats()
+        var = mops.mean(sq, axis=-1, key=_k(93))
+        assert mops.reduce_stats()["presum"] == 0
+        assert np.abs(np.asarray(reveal(var))
+                      - np.asarray((x * x).mean(-1))).max() < 1e-4
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,7 @@ from repro.mpc.ring import RING32
 from repro.spans import span
 
 CFG = dataclasses.replace(TINY_TARGET, vocab_size=64, n_layers=1,
-                          d_model=32, n_heads=2, n_kv_heads=2, d_head=16,
+                          d_model=96, n_heads=2, n_kv_heads=2, d_head=16,
                           d_ff=64)
 SPEC = ProxySpec(1, 2, 4)
 SEQ, BATCH, WAVE, CLASSES = 8, 8, 2, 2
@@ -66,8 +66,13 @@ def _trace_phase(out, protocol="2pc"):
 
 
 @pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    return _trace_phase(tmp_path_factory.mktemp("trace"))
+def trace_dir(tmp_path_factory):
+    return tmp_path_factory.mktemp("trace")
+
+
+@pytest.fixture(scope="module")
+def traced(trace_dir):
+    return _trace_phase(trace_dir)
 
 
 def _named(spans, name):
@@ -171,3 +176,67 @@ def test_every_backend_spans_its_primitives(protocol, tmp_path):
     assert min(dealer, online, local) >= 0 and online > 0
     assert (dealer > 0) == (protocol in ("2pc", "spdz2pc"))
     assert dealer + online + local == pytest.approx(fwd, rel=1e-9)
+
+
+def _site_spans(path, name, site):
+    """(start_ns, dur_ns) of every host event `name` whose meta `site`
+    is `site` (bench/span_reduce.load keeps names only)."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((e.start_ns, e.duration_ns) for e in line.events
+                           if e.name == name
+                           and (("site", site) in list(e.stats)))
+    return out
+
+
+@pytest.mark.parametrize("protocol", ["2pc", "spdz2pc", "3pc", "aby3trunc"])
+def test_presum_trunc_spans_as_counted(protocol, tmp_path):
+    """The variance of a 96-wide product inside a stand-in LayerNorm
+    stage: its pre-sum truncation is one `site="presum"` trunc span
+    there, as ops.reduce_stats counts it, and the split still sums."""
+    key = jax.random.key(4)
+    x = sharing.share(key, 0.5 * jax.random.normal(key, (4, 96)), RING32,
+                      protocol)
+    sq = mops.mul(x, x, jax.random.fold_in(key, 1))
+    s0 = mops.reduce_stats()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("wave.forward"), span("stage.ln_stats"):
+            var = mops.mean(sq, axis=-1, key=jax.random.fold_in(key, 2))
+            jax.block_until_ready(var.sh)
+    finally:
+        jax.profiler.stop_trace()
+    s1 = mops.reduce_stats()
+    path = trace_reduce.latest_xplane(str(tmp_path))
+    presum = _site_spans(path, "sf.mpc.trunc", "presum")
+    spans = span_reduce.load(path)
+    (ln,) = _named(spans, "sf.stage.ln_stats")
+    assert s1["presum"] - s0["presum"] == 1
+    assert len(presum) == 1 and _within(presum[0], ln)
+    ((fwd, dealer, online, local),) = span_reduce.wave_split(spans)
+    assert (dealer > 0) == (protocol in ("2pc", "spdz2pc"))
+    assert dealer + online + local == pytest.approx(fwd, rel=1e-9)
+
+
+def test_phase_presum_truncs_sit_in_ln_stats(traced, trace_dir):
+    """The traced phase at hidden 96: two pre-sum truncations a wave, the
+    variance's inside `sf.stage.ln_stats` and the pool's (8 positions of
+    the 2f residual) inside `sf.stage.head`; the canonical layer input
+    needs none. As many as the phase's DeviceReport counts; both
+    statistics encode 1/3, the pool's 1/8 folds."""
+    spans, rep, n_waves = traced
+    presum = _site_spans(trace_reduce.latest_xplane(str(trace_dir)),
+                         "sf.mpc.trunc", "presum")
+    ln = _in_forward(spans, "sf.stage.ln_stats")
+    head = _in_forward(spans, "sf.stage.head")
+    assert len(ln) == len(head) == n_waves == 2
+    assert (rep.device.reduce_presum, rep.device.reduce_odd_recip) == (4, 4)
+    in_ln = [p for p in presum if any(_within(p, s) for s in ln)]
+    in_head = [p for p in presum if any(_within(p, s) for s in head)]
+    assert len(in_ln) == len(in_head) == n_waves
+    assert len(in_ln) + len(in_head) == rep.device.reduce_presum
+    d = rep.device.as_dict()
+    assert (d["reduce_presum"], d["reduce_odd_recip"]) == (4, 4)
