@@ -344,11 +344,12 @@ class PhaseRun:
                 # weights resident once per phase: each party's share
                 # components on its pod slice, value dims replicated
                 self.pp_sh = sharding.place_party_tree(self.pp_sh)
-        # the kernel-path, dealer and reduction witnesses count this phase's
-        # forwards only, not the probe above (a cold probe traces every
-        # combine and draw too)
+        # the kernel-path, dealer, opening and reduction witnesses count
+        # this phase's forwards only, not the probe above (a cold probe
+        # traces every combine, draw and opening too)
         self.smm0 = kops.smm_stats()
         self.dealer0 = additive2pc.dealer_stats()
+        self.open0 = additive2pc.open_stats()
         self.reduce0 = mops.reduce_stats()
         self.t0 = time.perf_counter()
 
@@ -491,6 +492,9 @@ class PhaseRun:
         dealer1 = additive2pc.dealer_stats()
         dev.dealer_draws = dealer1["draws"] - self.dealer0["draws"]
         dev.dealer_traces = dealer1["traces"] - self.dealer0["traces"]
+        open1 = additive2pc.open_stats()
+        dev.open_calls = open1["calls"] - self.open0["calls"]
+        dev.open_traces = open1["traces"] - self.open0["traces"]
         reduce1 = mops.reduce_stats()
         dev.reduce_presum = reduce1["presum"] - self.reduce0["presum"]
         dev.reduce_odd_recip = (reduce1["odd_recip"]

@@ -181,7 +181,9 @@ class DeviceReport:
     rather than the jnp ref fallback. The dealer_* counters are the
     additive2pc dealer's deltas over the phase: draws made, and traces
     of its jitted draws (0 once every draw shape of the phase is
-    cached). The reduce_* counters are mpc/ops.mean's deltas: forced
+    cached). The open_* counters are the additive2pc openings' deltas:
+    Beaver and truncation openings made, and traces of their jitted
+    cores (0 once warm). The reduce_* counters are mpc/ops.mean's deltas: forced
     pre-sum truncations and odd-reciprocal encodings."""
     placement: str
     n_devices: int
@@ -192,6 +194,8 @@ class DeviceReport:
     combine_padded: int = 0
     dealer_draws: int = 0
     dealer_traces: int = 0
+    open_calls: int = 0
+    open_traces: int = 0
     reduce_presum: int = 0
     reduce_odd_recip: int = 0
 
@@ -215,6 +219,8 @@ class DeviceReport:
             "combine_padded": self.combine_padded,
             "dealer_draws": self.dealer_draws,
             "dealer_traces": self.dealer_traces,
+            "open_calls": self.open_calls,
+            "open_traces": self.open_traces,
             "reduce_presum": self.reduce_presum,
             "reduce_odd_recip": self.reduce_odd_recip,
             "waves": [dataclasses.asdict(w) for w in self.waves],

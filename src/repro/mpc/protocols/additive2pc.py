@@ -158,6 +158,55 @@ def triple_bytes(a_shape, b_shape, c_shape, ring: RingSpec) -> int:
 
 
 # ---------------------------------------------------------------------------
+# the openings
+#
+# As for the dealer: each opening's online arithmetic (masking, the sum
+# of both components, and for a truncation the public shift and the
+# subtraction) is a module-level jitted core, static in ring (and
+# shift), keyed by jit on shapes. The backend records the flight
+# OUTSIDE it, so records fire once per opening, also under an outer
+# trace. Ring arithmetic wraps either way: the compiled opening is
+# bitwise the eager one.
+# ---------------------------------------------------------------------------
+
+# `calls` counts openings (Beaver flights and truncation opens),
+# `traces` runs of a core's Python body (only when jit traces it): the
+# executor snapshots both around a phase, as for the dealer
+_open_stats = {"calls": 0, "traces": 0}
+
+
+def open_stats() -> dict:
+    """Snapshot of the openings' call and trace counters."""
+    return dict(_open_stats)
+
+
+def reset_open_stats() -> None:
+    for k in _open_stats:
+        _open_stats[k] = 0
+
+
+def _masked(xs, masks) -> tuple:
+    """Each party's masked components `x - mask`, x broadcast to the
+    mask's (dealer-drawn) shape."""
+    return tuple(jnp.broadcast_to(x, m.shape) - m for x, m in zip(xs, masks))
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _open_raw(xs, masks, ring):
+    _open_stats["traces"] += 1
+    return tuple(t[0] + t[1] for t in _masked(xs, masks))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _trunc_open_raw(x, r, r_t, ring, shift):
+    _open_stats["traces"] += 1
+    masked = x + r
+    m_t = (masked[0] + masked[1]) >> shift
+    pub = jnp.stack([m_t, jnp.zeros_like(m_t)])
+    return pub - r_t
+
+
+# ---------------------------------------------------------------------------
 # the backend
 # ---------------------------------------------------------------------------
 
@@ -178,29 +227,37 @@ class Additive2PC(BackendDefaults):
         return 2 * ring.elem_bytes * n
 
     # -- openings -------------------------------------------------------
-    def _open_flight(self, op: str, tensors, ring: RingSpec, *, n: int,
+    def _open_flight(self, op: str, xs, masks, ring: RingSpec, *, n: int,
                      flops: int = 0):
-        """Open masked share tensors in ONE simultaneous message flight.
+        """Open `x - mask` for every (x, mask) pair in ONE simultaneous
+        message flight.
 
         All tensors of a flight ride the same round trip (each party
-        sends its shares of every tensor at once), so the flight costs
-        1 round and 2 * elem_bytes * total-elements on the wire. This is
-        the unit the wave executor schedules: under comm.wave_scope the
-        flight's bytes scale with the wave while latency-bound flights
-        keep their rounds.
+        sends its masked components of every tensor at once), so the
+        flight costs 1 round and 2 * elem_bytes * total-elements on the
+        wire. This is the unit the wave executor schedules: under
+        comm.wave_scope the flight's bytes scale with the wave while
+        latency-bound flights keep their rounds.
 
-        The record's payload IS the flight: party p's masked components
-        of every tensor, routed to the peer — what `--wire` runs
-        serialize onto the real transport (comm.WireTape).
+        The masking and the sum run as one cached program (`_open_raw`;
+        each x broadcasts to its mask's shape inside it); the record
+        stays outside it. The record's payload IS the flight: party p's
+        masked components of every tensor, routed to the peer — what
+        `--wire` runs serialize onto the real transport
+        (comm.WireTape). It is built, eagerly from the same operands,
+        only when a WireTape is ambient: nothing else reads it.
         """
         with span("mpc.open"):
-            wire_elems = sum(numel(t.shape[1:]) for t in tensors)
+            payload = None
+            if comm.get_wire_tape() is not None:
+                payload = [(p, 1 - p, t[p])
+                           for t in _masked(xs, masks) for p in (0, 1)]
+            wire_elems = sum(numel(m.shape[1:]) for m in masks)
             comm.record(op, rounds=1,
                         nbytes=2 * ring.elem_bytes * wire_elems,
-                        numel=n, flops=flops, tag="bw",
-                        payload=[(p, 1 - p, t[p])
-                                 for t in tensors for p in (0, 1)])
-            return tuple(t[0] + t[1] for t in tensors)
+                        numel=n, flops=flops, tag="bw", payload=payload)
+            _open_stats["calls"] += 1
+            return _open_raw(xs, masks, ring)
 
     # -- truncation -----------------------------------------------------
     def trunc(self, x, key: jax.Array | None, *, shift: int | None = None,
@@ -214,7 +271,13 @@ class Additive2PC(BackendDefaults):
         of r>>shift. Costs one opening round plus the pair's offline
         bytes — the SAME cost for any shift, which is why folding a
         chain of deferred rescales into one trunc(shift=excess) is a
-        straight win for the dealer channel."""
+        straight win for the dealer channel.
+
+        The online half (mask, open, shift, subtract) runs as one cached
+        program (`_trunc_open_raw`, static in ring and shift), with the
+        `trunc_open` record outside it; the record's payload, each
+        party's masked component, is built only when a WireTape is
+        ambient."""
         ring = x.ring
         shift = ring.frac_bits if shift is None else shift
         out_fb = x.fb - shift
@@ -226,15 +289,17 @@ class Additive2PC(BackendDefaults):
         with span("mpc.trunc", site=site):
             r, r_t = trunc_pair(key, x.shape, ring, shift)
             with span("mpc.open"):
-                masked = x.sh + r.sh
-                m = masked[0] + masked[1]
+                payload = None
+                if comm.get_wire_tape() is not None:
+                    masked = x.sh + r.sh
+                    payload = [(0, 1, masked[0]), (1, 0, masked[1])]
                 comm.record("trunc_open", rounds=1,
                             nbytes=2 * ring.elem_bytes * numel(x.shape),
                             numel=numel(x.shape), tag="bw",
-                            payload=[(0, 1, masked[0]), (1, 0, masked[1])])
-            m_t = m >> shift
-            pub = jnp.stack([m_t, jnp.zeros_like(m_t)])
-            return x.with_scale(pub - r_t.sh, out_fb)
+                            payload=payload)
+                _open_stats["calls"] += 1
+                sh = _trunc_open_raw(x.sh, r.sh, r_t.sh, ring, shift)
+            return x.with_scale(sh, out_fb)
 
     # -- multiplication -------------------------------------------------
     def mul(self, x, y, key: jax.Array):
@@ -242,13 +307,10 @@ class Additive2PC(BackendDefaults):
         the raw product — `mpc/ops.py` owns the scale bookkeeping."""
         ring = x.ring
         shape = jnp.broadcast_shapes(x.shape, y.shape)
-        xb = jnp.broadcast_to(x.sh, (2,) + shape)
-        yb = jnp.broadcast_to(y.sh, (2,) + shape)
         a, b, c = mul_triple(key, shape, ring)
-        eps = xb - a.sh
-        dlt = yb - b.sh
         n = numel(shape)
-        eps_o, dlt_o = self._open_flight("beaver_mul", (eps, dlt), ring,
+        eps_o, dlt_o = self._open_flight("beaver_mul", (x.sh, y.sh),
+                                         (a.sh, b.sh), ring,
                                          n=n, flops=4 * n)
         with span("mpc.combine"):
             z = c.sh + eps_o * b.sh + dlt_o * a.sh
@@ -273,13 +335,12 @@ class Additive2PC(BackendDefaults):
         """
         ring = x.ring
         a, b, c = matmul_triple(key, x.shape, y.shape, ring)
-        eps = x.sh - a.sh
-        dlt = y.sh - b.sh
         n = numel(x.shape) + numel(y.shape)
         m, k = x.shape[-2], x.shape[-1]
         n_out = y.shape[-1]
         batch = numel(x.shape[:-2])
-        eps_o, dlt_o = self._open_flight("beaver_matmul", (eps, dlt), ring,
+        eps_o, dlt_o = self._open_flight("beaver_matmul", (x.sh, y.sh),
+                                         (a.sh, b.sh), ring,
                                          n=n, flops=2 * batch * m * k * n_out)
         # party-local: z_p = c_p + eps@b_p + a_p@dlt ; party0 adds eps@dlt
         # Kernel eligibility: 2-D weights on the right. Batched left
