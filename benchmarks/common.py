@@ -45,26 +45,3 @@ def tiny_exec_setup(seed: int, *, seq: int = 8, n_classes: int = 2):
     pp = proxy_mod.random_proxy(jax.random.key(seed), cfg, spec,
                                 seq_len=seq, n_classes=n_classes)
     return cfg, spec, pp
-
-
-def assert_mirror(report, cfg, spec, *, batch: int, seq: int,
-                  n_classes: int) -> None:
-    """The executed per-batch op stream must equal the analytic mirror
-    (mpc/costs.proxy_exec_cost) to exact integer equality, and the phase
-    ledger must equal the makespan model's inputs. The mirror is
-    parameterized by how the report says the stream was produced
-    (ring / protocol backend / fused), so this holds for every
-    ExecConfig combination."""
-    from repro.mpc import costs
-
-    assert report.agrees()
-    pb = report.per_batch
-    ana = costs.proxy_exec_cost(batch, seq, cfg.d_model, spec.n_heads,
-                                cfg.n_kv_heads, cfg.d_head, spec.mlp_dim,
-                                n_classes, spec.n_layers,
-                                ring=report.ring, protocol=report.protocol,
-                                fused=report.fused)
-    assert (pb.rounds, pb.lat_rounds, pb.nbytes, pb.offline_nbytes,
-            pb.flops) == \
-        (ana.rounds, ana.lat_rounds, ana.nbytes, ana.offline_nbytes,
-         ana.flops)

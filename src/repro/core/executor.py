@@ -20,13 +20,13 @@ ambient Ledger through comm.wave_scope, and the phase ledger must satisfy
 `iosched.ledger_agrees` — the same integers the analytic makespan prices.
 The per-batch reference ledger comes from `engine.TraceEngine` — the
 abstract `jax.eval_shape` probe of the identical op stream (zero FLOPs
-spent) — which in turn is pinned record-for-record to
-`mpc/costs.proxy_exec_cost`.  The forward itself is the unified
-engine-generic one (`engine/forward.py`) interpreted by an `MPCEngine`
-over this executor's ring and protocol backend; RING64 and RING32 run
-the same code path, and so do the additive-2PC (dealer Beaver) and
-replicated-3PC (dealer-free) sharing schemes — `ExecConfig.protocol`
-picks the backend, the party axis sizes itself accordingly.
+spent), the one accountant of that stream.  The forward itself is the
+unified engine-generic one (`engine/forward.py`) interpreted by an
+`MPCEngine` over this executor's ring and protocol backend; RING64 and
+RING32 run the same code path, and so do the additive-2PC (dealer
+Beaver) and replicated-3PC (dealer-free) sharing schemes —
+`ExecConfig.protocol` picks the backend, the party axis sizes itself
+accordingly.
 
 On a pod mesh the wave dimension is a logical sharding axis ("wave" ->
 the data axis; parallel/sharding.py), so W concurrent batches land on
@@ -134,11 +134,7 @@ class PhaseReport:
     n_waves: int
     wall_s: float
     sched: iosched.SchedConfig
-    # how the stream was produced — what the analytic mirror must be
-    # parameterized with to reproduce it (benchmarks/common.assert_mirror)
-    ring: RingSpec = RING64
     protocol: str = "2pc"
-    fused: bool = True
     # real-wire outcome (net.WireReport) when the phase ran with
     # ExecConfig.wire != "none": measured wire_makespan_s, reconciled
     # byte counts, payload digests
@@ -521,8 +517,8 @@ class PhaseRun:
         rep = PhaseReport(
             ledger=self.phase_led, per_batch=self.per_batch,
             n_batches=self.n_batches, n_waves=self.n_waves, wall_s=wall_s,
-            sched=cfg.sched(), ring=self.ring, protocol=self.proto,
-            fused=cfg.fuse, wire=wire_rep, device=dev)
+            sched=cfg.sched(), protocol=self.proto, wire=wire_rep,
+            device=dev)
         return AShare(out, self.ring, self.proto), rep
 
 

@@ -6,15 +6,16 @@ path (in-vivo training, efficacy numbers), the share-level MPC path
 cost probe.  Clear/MPC parity is structural, not maintained by
 discipline: there is no second copy of the layer math to drift.
 
-The op order below is load-bearing for the accounting contract: the
-MPC op stream it induces is mirrored record-for-record by
-`mpc/costs.proxy_exec_cost`, and the wave executor's realized flight
-ledger must reproduce that stream exactly (`iosched.ledger_agrees`).
-Reorder ops here and the mirror test tells you immediately.  The
-`eng.fused(label)` groups are part of that contract too: under a
-`fusion.flight_scope` they bound the fused flights, and the analytic
-mirror places its GroupBegin/GroupEnd markers at the same spots —
-move a group here and `proxy_exec_cost(fused=True)` must move with it.
+The op order below is the accounting: the `TraceEngine` probe prices
+a batch by running this function, and the wave executor's realized
+flight ledger must reproduce the probe's stream exactly
+(`PhaseReport.agrees`, `iosched.ledger_agrees`).  The
+`eng.fused(label)` groups bound the fused flights under a
+`fusion.flight_scope`.  The per-batch streams of the tests' geometries
+are pinned in tests/data/probe_streams.txt: reorder ops or move a group
+here on purpose, regenerate that file (`python
+tests/data/probe_streams.py`), and its diff lists the records that
+moved.
 
 Each stage runs inside an `sf.stage.<name>` span (repro/spans.py), so
 a profiler trace shows where the host's dispatch of the forward goes.

@@ -49,7 +49,9 @@ class TraceEngine:
         `pp_sh` may hold real share arrays or ShapeDtypeStructs — both
         flow through eval_shape untouched.  `fused=True` probes the
         round-compressed stream (the op trace runs under
-        `fusion.flight_scope`, exactly as the executor runs it).
+        `fusion.flight_scope`, exactly as the executor runs it). The
+        same stream comes back inside a `comm.wave_scope` or under a
+        `WireTape`, and nothing lands on the tape.
         """
         ring = self.ring
         proto = self.protocol
@@ -65,7 +67,10 @@ class TraceEngine:
                                      spec, variant).sh
 
         ctx = x64_scope() if ring.bits >= 64 else contextlib.nullcontext()
-        with ctx, comm.ledger_scope() as led:
+        # hermetic: an ambient wave scope must not scale the per-batch
+        # records, and a probe is no execution to capture on a WireTape
+        with ctx, comm.ledger_scope() as led, comm.wave_scope(1), \
+                comm.wire_tape_scope(None):
             jax.eval_shape(fwd, pp_sh,
                            jax.ShapeDtypeStruct(
                                (n_parties,) + tuple(batch_shape),

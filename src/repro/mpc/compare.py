@@ -5,8 +5,8 @@ communication cost, exactly as stated by the paper (§4.1): one pairwise
 comparison of two secret values costs 8 communication rounds and 432
 bytes, and reveals only the binary outcome. The same functionality cost
 is charged on every protocol backend (2pc binary-share conversion and
-3pc bit-decomposition land in the same ballpark; the ledger mirror in
-mpc/costs.py charges the identical record either way).
+3pc bit-decomposition land in the same ballpark; `costs.cmp_cost`
+charges the identical record either way).
 
 Implementation note (DESIGN.md §8): real comparison needs binary share
 conversion (B2A/edaBits). Semantics here are computed from the summed

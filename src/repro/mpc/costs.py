@@ -1,9 +1,12 @@
 """Analytic MPC cost formulas (rounds / bytes / local flops).
 
-These mirror the executable protocols in ops.py/nonlinear.py exactly but
-evaluate at *paper scale* (BERT over 42K-188K candidates) without moving
-tensors, producing the Ledgers that fig2/fig6/fig7 benchmarks and the IO
-scheduler consume. Element size defaults to CrypTen's int64 ring (8 B).
+Per-primitive formulas that evaluate at *paper scale* (BERT over
+42K-188K candidates) without moving tensors, producing the Ledgers that
+fig2/fig6/fig7 benchmarks and the IO scheduler consume. Element size
+defaults to CrypTen's int64 ring (8 B). The executed proxy forward is
+not priced here: its accountant is the `engine.TraceEngine` probe,
+which runs the forward itself, and `PhaseReport.agrees` checks each
+executed phase against it.
 
 Tag convention ("bw" bandwidth-bound / "lat" latency-bound) feeds the
 paper's §4.4 scheduler: comparisons and low-dim ops are "lat", big-tensor
@@ -12,11 +15,12 @@ Beaver openings are "bw".
 Ring parameterization: the primitive helpers take an optional RingSpec.
 RING64 (default) truncates locally — free, no record, CrypTen's choice.
 RING32 (the TPU ring) uses dealer-assisted truncation: every fixed-point
-product pays one extra opening round (`trunc_open`), mirrored here
-record-for-record against the dealer path of `Additive2PC.trunc`.
+product pays one extra opening round (`trunc_open`), as the dealer path
+of `Additive2PC.trunc` records it.
 
 Protocol parameterization: the same primitives take `protocol=` and
-mirror the chosen backend's records exactly:
+price the chosen backend's records (tests/test_conformance.py holds
+each primitive to its backend):
   2pc       Beaver opening flights (bytes ~ inputs) + dealer bytes in
             the OFFLINE channel (tag="offline", 0 rounds: triples and,
             on RING32, truncation pairs) in the positions the
@@ -28,10 +32,8 @@ mirror the chosen backend's records exactly:
             profile.
   spdz2pc   the malicious tier: MAC'd dealer randomness (4 components
             per element — DOUBLE the semi-honest offline bytes), a
-            sacrifice flight before every Beaver open, dealer
-            truncation pairs on BOTH rings, and the constant-size
-            batched MAC check + MAC-key shipment at the forward
-            boundary (`proxy_exec_cost` tail).
+            sacrifice flight before every Beaver open, and dealer
+            truncation pairs on BOTH rings.
   aby3trunc 3pc resharing costs everywhere, except each forced
             truncation is one exact `trunc2` subprotocol: rounds=2
             (a batcher barrier), 6 components of wire.
@@ -128,8 +130,8 @@ def mul_cost(n: int, op: str = "beaver_mul", *,
     """One secure elementwise multiply. `inline_trunc=True` prices the
     classic trunc-at-op-boundary stream (the CrypTen-style baselines);
     the executable scale-carrying ops emit the RAW product
-    (`inline_trunc=False`) and `proxy_exec_cost` places the forced
-    truncations where `mpc/scale.py` actually fires them."""
+    (`inline_trunc=False`) and force truncations only where
+    `mpc/scale.py` fires them."""
     if protocol in _P3:
         led = _led(CostRecord(op, 1, 3 * ring.elem_bytes * n, n,
                               6 * n, "bw"))
@@ -367,234 +369,6 @@ def proxy_model_cost(g: BlockGeom, layers: int, classes: int,
     led.records.extend(mlp_cost(g.batch, classes, mlp_hidden, 1,
                                 "proxy.mlp_se").records)
     return led
-
-
-def proxy_exec_cost(bsz: int, seq: int, d_model: int, heads: int,
-                    kv_heads: int, d_head: int, mlp_hidden: int,
-                    classes: int, n_layers: int,
-                    op: str = "exec", *, ring: RingSpec = RING64,
-                    protocol: str = "2pc", fused: bool = False) -> Ledger:
-    """EXACT mirror of the engine forward's share-level op stream.
-
-    Record-for-record prediction of what one batch of the executable
-    proxy forward (`engine/forward.proxy_entropy` under an MPCEngine)
-    puts on the wire — the contract the wave executor's TraceEngine
-    probe is tested against (tests/test_executor.py) and the per-batch
-    input fig7 feeds to iosched.makespan. Unlike `proxy_model_cost`
-    (paper-geometry pricing with fused QKV), this follows the executed
-    path: separate q/k/v openings, two LayerNorm affine multiplies, GQA
-    head grouping, and ring-dependent truncation — record-free local
-    shifts on RING64, dealer-assisted `trunc_open` rounds on RING32
-    (including the mean/scale `mul_public` truncations that are free on
-    RING64). Biases add no wire cost, so the formulas hold with or
-    without them.
-
-    `protocol="3pc"` mirrors the replicated-sharing stream: resharing
-    flights (output-proportional bytes) in place of Beaver openings,
-    0-round `trunc_reshare` bytes wherever a truncation is forced (the
-    re-replication component riding the resharing flight), and an
-    empty offline channel on both rings. `protocol="spdz2pc"` mirrors
-    the malicious tier (doubled MAC'd offline bytes, a sacrifice flight
-    per multiply, dealer truncation on both rings, and the boundary
-    mac_key/mac_check tail); `protocol="aby3trunc"` swaps every forced
-    truncation for the 2-round exact `trunc2` record.
-
-    `fused=True` mirrors the round-compressed stream instead: the eager
-    event stream below — with GroupBegin/GroupEnd markers placed exactly
-    where `engine/forward.py` opens its `eng.fused` groups — is replayed
-    through `fusion.compress_events`, i.e. the very FlightBatcher the
-    executed path batches with, so flush semantics cannot drift between
-    model and execution.
-
-    The stream is SCALE-SIMULATED: multiplies emit raw products at the
-    summed exponent (`inline_trunc=False`) and forced truncations land
-    exactly where the `mpc/scale.py` lattice — the SAME decision
-    procedure the executable ops consult — fires them: power-of-two
-    rescales (pow2 means, `d_head**-0.5`) fold for free, comparison
-    bits multiply at exponent 0 (ReLU is truncation-free), a tensor
-    consumed by several scale-sensitive ops truncates once (the
-    ops.force memo), and forcing a broadcast bills the pre-broadcast
-    element count (layout lineage). That is the cross-op deferred-
-    truncation contract this mirror certifies record-for-record.
-    """
-    from repro.mpc import fusion, protocols, scale as lattice
-
-    f = ring.frac_bits
-    # headroom-cap bits handed to the scale lattice: mirrors
-    # ops._headroom_bits — only exact-trunc backends (spdz2pc,
-    # aby3trunc) may defer to the ring-wide 3f cap; probabilistic
-    # local-trunc backends keep 2f (bits=None)
-    hbits = ring.bits if protocols.get(protocol).exact_trunc else None
-    w, wk = heads, min(kv_heads, heads)
-    t = bsz * seq
-    events: list = []
-    kw = dict(ring=ring, protocol=protocol)
-
-    def ext(led: Ledger) -> None:
-        events.extend(led.records)
-
-    class V:
-        """Symbolic scale-carrying tensor: carried exponent, the element
-        count a forced truncation bills (its lineage ROOT's numel), and
-        the per-target force memo mirroring ops.force's cache."""
-
-        def __init__(self, fb: int, n: int):
-            self.fb, self.n, self.forced = fb, n, set()
-
-    W = V(f, 0)                       # shared weights: always canonical
-
-    def forced(v: V, name: str, to: int) -> None:
-        if v.fb <= to or to in v.forced:
-            return
-        ext(trunc_cost(v.n, f"{op}.{name}", **kw))
-        v.forced.add(to)
-
-    def mul_pub(v: V, c: float, name: str, n_out: int) -> V:
-        k = lattice.pow2_exponent(c)
-        if k is not None:             # free exponent fold
-            return V(v.fb - k, n_out)
-        _, shift, out_fb = lattice.mul_public_plan(v.fb, c, f, hbits)
-        if shift:
-            forced(v, name, v.fb - shift)
-        return V(out_fb, n_out)
-
-    def mean(v: V, n: int, name: str, n_out: int) -> V:
-        """ops.mean: the pre-sum force of the reduction rule (billed at
-        the input's elements), the free 2**-k fold, then 1/m."""
-        shift = lattice.sum_plan(v.fb, n, f, ring.bits)
-        if shift:
-            forced(v, f"{name}.presum", v.fb - shift)
-        k, m = lattice.split_reciprocal(n)
-        s = V(v.fb - shift + k, n_out)
-        return s if m == 1 else mul_pub(s, 1.0 / m, f"{name}.force", n_out)
-
-    def mul2(x: V, y: V, name: str, n: int) -> V:
-        px, py, out_fb = lattice.mul_plan(x.fb, y.fb, f, hbits)
-        if px:
-            forced(x, f"{name}.x", x.fb - px)
-        if py and y is not x:
-            forced(y, f"{name}.y", y.fb - py)
-        ext(mul_cost(n, f"{op}.{name}", inline_trunc=False, **kw))
-        return V(out_fb, n)
-
-    def mm(x: V, y: V, name: str, batch: int, m: int, kk: int,
-           n: int) -> V:
-        px, py, out_fb = lattice.mul_plan(x.fb, y.fb, f, hbits)
-        if px:
-            forced(x, f"{name}.x", x.fb - px)
-        if py and y is not x:
-            forced(y, f"{name}.y", y.fb - py)
-        ext(matmul_cost(batch, m, kk, n, f"{op}.{name}",
-                        inline_trunc=False, **kw))
-        return V(out_fb, batch * m * n)
-
-    def mlp(x: V, rows: int, d_in: int, hid: int, d_out: int,
-            name: str) -> V:
-        h = mm(x, W, f"{name}.fc1", 1, rows, d_in, hid)
-        # ReLU: comparison (scale-invariant) + bit multiply at exponent
-        # 0 — truncation-free, output keeps h's exponent
-        ext(cmp_cost(rows * hid, f"{op}.{name}.relu.cmp"))
-        r = mul2(h, V(0, rows * hid), f"{name}.relu.mul", rows * hid)
-        return mm(r, W, f"{name}.fc2", 1, rows, hid, d_out)
-
-    x_fb = f                          # shared activations enter canonical
-    for _ in range(n_layers):
-        # MLP-LayerNorm: each mean forces its input where the sum would
-        # outgrow the ring and folds the power of two of 1/d for free;
-        # the centered activation truncates ONCE (memo) though both the
-        # variance square and the normalize multiply consume it
-        events.append(fusion.GroupBegin("ln_stats"))
-        mu = mean(V(x_fb, t * d_model), d_model, "ln.mu", t)
-        # centering sub: exact lift unless mu's pow2 fold topped the 2f
-        # cap (layer >= 2, pow2 d) — then mu down-truncs KEYED, billed
-        # at its pre-broadcast rows (lineage)
-        align_fb = lattice.align_target(x_fb, mu.fb, f, hbits)
-        if mu.fb > align_fb:
-            forced(mu, "ln.mu.align", align_fb)
-        xc = V(align_fb, t * d_model)
-        var_p = mul2(xc, xc, "ln.var", t * d_model)
-        var = mean(var_p, d_model, "ln.var_mean", t)
-        events.append(fusion.GROUP_END)
-        inv = mlp(var, t, 1, mlp_hidden, 1, "mlp_ln")
-        # normalize: inv's force bills its pre-broadcast rows (lineage)
-        h = mul2(xc, inv, "ln.normmul", t * d_model)
-        h = mul2(h, W, "ln.affine", t * d_model)
-        ha = V(h.fb, t * d_model)     # + beta (lift, free): new object
-        # pruned attention: per-projection secure matmuls; one forced
-        # trunc of the shared input serves all three projections
-        events.append(fusion.GroupBegin("qkv"))
-        q = mm(ha, W, "q", 1, t, d_model, w * d_head)
-        k_ = mm(ha, W, "k", 1, t, d_model, wk * d_head)
-        v_ = mm(ha, W, "v", 1, t, d_model, wk * d_head)
-        events.append(fusion.GROUP_END)
-        scores = mm(q, k_, "scores", bsz * w, seq, d_head, seq)
-        scores = mul_pub(scores, d_head ** -0.5, "scores.scale.force",
-                         bsz * w * seq * seq)
-        probs = mlp(scores, bsz * w * seq, seq, mlp_hidden, seq, "mlp_sm")
-        o = mm(probs, v_, "av", bsz * w, seq, seq, d_head)
-        out = mm(o, W, "out", 1, t, w * d_head, d_model)
-        x_fb = lattice.align_target(x_fb, out.fb, f, hbits)  # residual
-    pooled = mean(V(x_fb, t * d_model), seq, "pool", bsz * d_model)
-    logits = mm(pooled, W, "head", 1, bsz, d_model, classes)
-    ent = mlp(logits, bsz, classes, mlp_hidden, 1, "mlp_se")
-    # the engine's entropy head forces its output canonical — the
-    # forward's public boundary (QuickSelect consumes fb == frac_bits)
-    forced(ent, "entropy.force", f)
-    if protocol == "spdz2pc":
-        # the malicious boundary: dealer MAC-key shipment + ONE batched
-        # MAC check for every partial open of the forward (constant
-        # size), in the order spdz2pc.mac_check_flight records them
-        events.append(CostRecord(f"{op}.mac_key", 0, 2 * ring.elem_bytes,
-                                 1, 0, "offline"))
-        events.append(CostRecord(f"{op}.mac_check", 1,
-                                 4 * ring.elem_bytes, 1, 0, "bw"))
-    if fused:
-        return fusion.compress_events(events)
-    led = Ledger()
-    led.records.extend(r for r in events
-                       if not isinstance(r, (fusion.GroupBegin,
-                                             fusion.GroupEnd)))
-    return led
-
-
-def pr4_trunc_baseline(bsz: int, seq: int, d_model: int, heads: int,
-                       kv_heads: int, d_head: int, mlp_hidden: int,
-                       classes: int, n_layers: int, *,
-                       ring: RingSpec = RING64) -> tuple[int, int]:
-    """FROZEN PR 4 baseline: (truncation events, dealer trunc-pair
-    bytes) of the pre-scale-carrying RING32 2PC proxy stream, where
-    every mul/matmul/mul_public/mean forced its own truncation at the
-    op boundary (18 events per layer + 5 tail). This is the regression
-    reference `bench_fusion --smoke` gates the >=25% event reduction
-    against — do NOT update it to track the live stream."""
-    w, wk = heads, min(kv_heads, heads)
-    t = bsz * seq
-    rows = bsz * w * seq
-    per_layer = [
-        t,                      # mean trunc
-        t * d_model,            # var mul
-        t,                      # var mean
-        t * mlp_hidden,         # mlp_ln fc1
-        t * mlp_hidden,         # mlp_ln relu mul
-        t,                      # mlp_ln fc2
-        t * d_model,            # normmul
-        t * d_model,            # affine
-        t * w * d_head,         # q
-        t * wk * d_head,        # k
-        t * wk * d_head,        # v
-        bsz * w * seq * seq,    # scores matmul
-        bsz * w * seq * seq,    # scores scale
-        rows * mlp_hidden,      # mlp_sm fc1
-        rows * mlp_hidden,      # mlp_sm relu mul
-        rows * seq,             # mlp_sm fc2
-        bsz * w * seq * d_head,  # av
-        t * d_model,            # out
-    ]
-    tail = [bsz * d_model, bsz * classes, bsz * mlp_hidden,
-            bsz * mlp_hidden, bsz]
-    numels = per_layer * n_layers + tail
-    # one dealer pair per event: (r, r>>f) = 2 tensors, both parties
-    return len(numels), sum(4 * ring.elem_bytes * n for n in numels)
 
 
 def mpcformer_block_cost(g: BlockGeom) -> Ledger:

@@ -46,10 +46,9 @@ the ledger's offline channel without flushing anything.
 Everything here is accounting: the batcher intercepts `comm.record`
 calls, so the PRNG key stream, the dealer triples, and every share an
 op produces are identical to the eager path (asserted bitwise across
-all variant sets in tests/test_fusion.py). `compress_events` replays an
-analytic record stream through the same batcher, which is how
-`costs.proxy_exec_cost(fused=True)` mirrors the fused stream
-record-for-record without a second implementation of flush semantics.
+all variant sets in tests/test_fusion.py). The fused stream's cost is
+read off the same batcher: `TraceEngine.probe(fused=True)` runs the
+forward under a `flight_scope`, exactly as the executor does.
 """
 from __future__ import annotations
 
@@ -271,52 +270,3 @@ def barrier() -> None:
     fb = get_batcher()
     if fb is not None:
         fb.flush()
-
-
-# ---------------------------------------------------------------------------
-# analytic replay (the costs.py mirror)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class GroupBegin:
-    label: str
-
-
-class GroupEnd:
-    pass
-
-
-GROUP_END = GroupEnd()
-
-
-def compress_events(events) -> comm.Ledger:
-    """Replay an eager record stream (CostRecords interleaved with
-    GroupBegin/GROUP_END markers) through a FlightBatcher.
-
-    This IS the analytic mirror's fusion step: flush semantics exist
-    once, here, so `costs.proxy_exec_cost(fused=True)` and the executed
-    stream can only diverge if the event stream itself is wrong — which
-    the record-for-record tests catch.
-
-    The replay is hermetic: it opens its own ledger and pins the wave
-    multiplier to 1, so calling the analytic mirror from inside a
-    `comm.wave_scope` (e.g. executor instrumentation) cannot inflate
-    the per-batch records it predicts.
-    """
-    with comm.ledger_scope() as led:
-        # hermetic also against wire capture: the replay is an analytic
-        # mirror, not an execution — it must never append to an ambient
-        # WireTape
-        with comm.wave_scope(1), comm.wire_tape_scope(None), \
-                flight_scope() as fb:
-            for e in events:
-                if isinstance(e, GroupBegin):
-                    fb.flush()
-                    fb._label = e.label
-                elif isinstance(e, GroupEnd):
-                    fb.flush(fb._label)
-                    fb._label = None
-                else:
-                    comm.record(e.op, rounds=e.rounds, nbytes=e.nbytes,
-                                numel=e.numel, flops=e.flops, tag=e.tag)
-    return led

@@ -45,7 +45,7 @@ flight departs, so independent groups batch the same way. Both mark
 their flights tag="bw"; the batcher needs no scheme-specific code.
 
 Everything above this layer (`ops`, `compare`, `nonlinear`, the
-engines, the executor, the analytic mirror) is protocol-generic and
+engines, the executor, the cost probe) is protocol-generic and
 routes through `get(name)`.
 """
 from __future__ import annotations

@@ -1,4 +1,4 @@
-"""The fixed-point scale lattice — one algebra for execution AND mirror.
+"""The fixed-point scale lattice — one algebra for every truncation.
 
 A scale-carrying share encodes value v as round(v * 2**fb) where `fb`
 (the carried frac-bits exponent, `Share.fb`) is static pytree aux data.
@@ -34,10 +34,10 @@ bits (1/768 at 12 bits is 5/4096, 6.25% low; 1/3 is 1365/4096, 0.02%
 low).
 
 This module is the decision procedure only — pure functions of static
-exponents, shared verbatim by the executable ops (`mpc/ops.py`) and the
-analytic mirror (`mpc/costs.proxy_exec_cost`), so "where does a forced
-truncation fire" exists exactly once and the record-for-record mirror
-tests catch any drift.
+exponents that the executable ops (`mpc/ops.py`) consult, so "where
+does a forced truncation fire" exists exactly once. The
+`engine.TraceEngine` probe reads the truncations off the ops' own
+records; tests/data/probe_streams.txt pins them.
 """
 from __future__ import annotations
 
@@ -52,7 +52,7 @@ def cap(f: int, bits: int | None = None) -> int:
     (f=16, bits=64) caps at 48, RING32 (f=12, bits=32) stays at 24.
 
     Callers gate `bits` on the backend's truncation exactness
-    (`ops._headroom_bits` / the costs.py mirror): probabilistic local
+    (`ops._headroom_bits`): probabilistic local
     truncation wraps w.p. ~ encoded/2**bits, which a 3f exponent
     amplifies 2**f-fold, so only exact-trunc backends (spdz2pc,
     aby3trunc) pass their ring width here — everyone else passes None

@@ -10,8 +10,9 @@ Contracts:
   2. REMOVED SHIMS — the deprecated proxy_entropy_clear/_mpc and
      approx.mlp_apply/_mpc back-compat wrappers are gone: the engine
      API is the only entry point.
-  3. TRACE — TraceEngine's abstract probe equals the analytic mirror on
-     both rings without materializing weights (abstract_shares).
+  3. TRACE — TraceEngine prices variants without materializing weights
+     (abstract_shares); its pinned streams are checked in
+     tests/test_probe_streams.py.
   4. RESOLUTION — legacy mode strings resolve to engine instances.
 """
 import dataclasses
@@ -27,9 +28,10 @@ from repro.core.proxy import ProxySpec
 from repro.engine import (ClearEngine, MPCEngine, TraceEngine, VARIANTS,
                           abstract_shares, proxy_entropy, resolve_engine)
 from repro.engine.base import FULL_VARIANT, TensorEngine
-from repro.mpc import costs
 from repro.mpc.ring import RING32, RING64
 from repro.mpc.sharing import reveal, share
+
+from data import probe_streams as streams
 
 CFG = dataclasses.replace(TINY_TARGET, vocab_size=64, n_layers=1,
                           d_model=32, n_heads=2, n_kv_heads=2, d_head=16,
@@ -104,16 +106,12 @@ class TestParitySweep:
         got = np.asarray(reveal(proxy_entropy(eng, pp_sh, CFG, x_sh,
                                               SPEC)))
         assert np.abs(got - clear).max() < ATOL["full"]
-        # and the biased arch is priceable: probe emits the same record
-        # stream (biases add no wire cost — costs.proxy_exec_cost's
-        # documented contract)
+        # and the biased arch is priceable: the probe emits the pinned
+        # unbiased stream record for record (biases add no wire cost)
         led = TraceEngine(RING64).probe(pp_sh, CFG, SPEC,
                                         (BATCH, SEQ, CFG.d_model))
-        ana = costs.proxy_exec_cost(BATCH, SEQ, CFG.d_model, SPEC.n_heads,
-                                    CFG.n_kv_heads, CFG.d_head,
-                                    SPEC.mlp_dim, CLASSES, SPEC.n_layers)
-        assert (led.rounds, led.nbytes, led.flops) == \
-            (ana.rounds, ana.nbytes, ana.flops)
+        streams.assert_matches(
+            streams.Case("tiny", "2pc", "ring64", "eager", BATCH), led)
 
     def test_softmax_strategies_differ(self, pp):
         """The strategies are real: distinct softmax ops, distinct
@@ -147,27 +145,11 @@ class TestShimsRemoved:
 
 
 # ---------------------------------------------------------------------------
-# 3. TraceEngine: abstract probe == analytic mirror, no weights needed
+# 3. TraceEngine: prices variants, no weights needed
 # ---------------------------------------------------------------------------
 
 
 class TestTrace:
-    @pytest.mark.parametrize("ring", [RING64, RING32],
-                             ids=["ring64", "ring32"])
-    def test_abstract_probe_matches_mirror(self, ring):
-        pp_sh = abstract_shares(CFG, SPEC, SEQ, CLASSES, ring)
-        led = TraceEngine(ring).probe(pp_sh, CFG, SPEC,
-                                      (BATCH, SEQ, CFG.d_model))
-        ana = costs.proxy_exec_cost(BATCH, SEQ, CFG.d_model, SPEC.n_heads,
-                                    CFG.n_kv_heads, CFG.d_head,
-                                    SPEC.mlp_dim, CLASSES, SPEC.n_layers,
-                                    ring=ring)
-        assert len(led.records) == len(ana.records)
-        for got, want in zip(led.records, ana.records):
-            assert (got.rounds, got.nbytes, got.numel, got.flops, got.tag) \
-                == (want.rounds, want.nbytes, want.numel, want.flops,
-                    want.tag), (got, want)
-
     def test_baseline_softmaxes_cost_more(self):
         """quad/poly baselines pay reciprocal/comparison protocols the
         MLP emulator avoids — visible in the probed stream."""

@@ -3,8 +3,8 @@
 Three contracts:
   1. LEDGER AGREEMENT — the executor's recorded flights for a phase are
      exactly (integer equality) the inputs iosched.makespan prices, and
-     the measured per-batch op stream matches mpc/costs.proxy_exec_cost
-     record-for-record.
+     the measured per-batch op stream is the pinned probe stream
+     (tests/data/probe_streams.txt) record for record.
   2. SCHEDULE INVARIANCE — the four (coalesce, overlap) variants move
      flights around but never change a single share: scores are bitwise
      identical, so wave execution selects the same survivors as the
@@ -24,9 +24,11 @@ from repro.core import iosched
 from repro.core import proxy as proxy_mod
 from repro.core.executor import ExecConfig, WaveExecutor
 from repro.core.proxy import ProxySpec
-from repro.mpc import comm, costs, quickselect
+from repro.mpc import comm, quickselect
 from repro.mpc.comm import WAN, Ledger, ledger_scope
 from repro.mpc.ring import RING32, x64_scope
+
+from data import probe_streams as streams
 
 CFG = dataclasses.replace(TINY_TARGET, vocab_size=64, n_layers=1,
                           d_model=32, n_heads=2, n_kv_heads=2, d_head=16,
@@ -94,15 +96,9 @@ class TestWaveScope:
 
 class TestLedgerAgreement:
     def test_per_batch_probe_matches_analytic_exactly(self, executed):
-        pb = executed["ours"][1].per_batch
-        ana = costs.proxy_exec_cost(BATCH, SEQ, CFG.d_model, SPEC.n_heads,
-                                    CFG.n_kv_heads, CFG.d_head,
-                                    SPEC.mlp_dim, CLASSES, SPEC.n_layers)
-        assert len(pb.records) == len(ana.records)
-        for got, want in zip(pb.records, ana.records):
-            assert (got.rounds, got.nbytes, got.numel, got.flops, got.tag) \
-                == (want.rounds, want.nbytes, want.numel, want.flops,
-                    want.tag), (got, want)
+        streams.assert_matches(
+            streams.Case("tiny", "2pc", "ring64", "eager", BATCH),
+            executed["ours"][1].per_batch)
 
     def test_all_variants_agree_with_makespan_inputs(self, executed):
         for name, (_, rep) in executed.items():
@@ -155,19 +151,12 @@ class TestRing32:
         assert np.isfinite(np.asarray(ent.sh)).all()
 
     def test_ring32_probe_matches_analytic_mirror(self, ring32_report):
-        """costs.proxy_exec_cost(ring=RING32) mirrors the executed
-        stream record-for-record, dealer trunc_open rounds included."""
+        """The executed RING32 per-batch stream is the pinned one,
+        dealer trunc_open rounds included."""
         _, rep = ring32_report
-        ana = costs.proxy_exec_cost(BATCH, SEQ, CFG.d_model, SPEC.n_heads,
-                                    CFG.n_kv_heads, CFG.d_head,
-                                    SPEC.mlp_dim, CLASSES, SPEC.n_layers,
-                                    ring=RING32)
-        pb = rep.per_batch
-        assert len(pb.records) == len(ana.records)
-        for got, want in zip(pb.records, ana.records):
-            assert (got.rounds, got.nbytes, got.numel, got.flops, got.tag) \
-                == (want.rounds, want.nbytes, want.numel, want.flops,
-                    want.tag), (got, want)
+        streams.assert_matches(
+            streams.Case("tiny", "2pc", "ring32", "eager", BATCH),
+            rep.per_batch)
 
     def test_ring32_pays_trunc_rounds_but_fewer_bytes(self, ring32_report,
                                                       executed):

@@ -7,9 +7,10 @@ Contracts:
   2. COMPRESSION — the RING32 proxy forward under flight_scope records
      >= 40% fewer ledger rounds than the eager path (the dealer-trunc
      and Beaver openings fold into per-group flights).
-  3. MIRROR — costs.proxy_exec_cost(fused=True) predicts the fused
-     stream record-for-record, and an executed fused phase still
-     satisfies iosched.ledger_agrees.
+  3. PROBE — the fused probe pays fewer rounds at the same bytes, is
+     hermetic against ambient wave scopes and wire tapes, and an
+     executed fused phase reproduces its pinned stream
+     (tests/data/probe_streams.txt) and satisfies iosched.ledger_agrees.
   4. HOT PATH — MPCEngine.matmul's RING32 combine routes through the
      Pallas secure_matmul kernel bitwise-identically (ref + interpret).
 """
@@ -26,11 +27,13 @@ from repro.core import proxy as proxy_mod
 from repro.core.executor import ExecConfig, WaveExecutor
 from repro.core.proxy import ProxySpec
 from repro.engine import MPCEngine, TraceEngine, VARIANTS, abstract_shares, \
-    proxy_entropy
-from repro.mpc import comm, costs, fusion, ops as mops, quickselect
+    cached_probe, proxy_entropy
+from repro.mpc import comm, fusion, ops as mops, quickselect
 from repro.mpc.comm import ledger_scope
 from repro.mpc.ring import RING32, RING64, x64_scope
 from repro.mpc.sharing import share
+
+from data import probe_streams as streams
 
 CFG = dataclasses.replace(TINY_TARGET, vocab_size=64, n_layers=1,
                           d_model=32, n_heads=2, n_kv_heads=2, d_head=16,
@@ -194,49 +197,35 @@ class TestFusedParity:
 
 
 # ---------------------------------------------------------------------------
-# 3: analytic mirror + executed fused phase
+# 3: the fused probe + executed fused phase
 # ---------------------------------------------------------------------------
 
 class TestFusedMirror:
-    @pytest.mark.parametrize("ring", list(RINGS.values()), ids=list(RINGS))
-    def test_fused_probe_matches_mirror(self, ring):
-        pp_sh = abstract_shares(CFG, SPEC, SEQ, CLASSES, ring)
-        led = TraceEngine(ring).probe(pp_sh, CFG, SPEC,
-                                      (BATCH, SEQ, CFG.d_model), fused=True)
-        ana = costs.proxy_exec_cost(BATCH, SEQ, CFG.d_model, SPEC.n_heads,
-                                    CFG.n_kv_heads, CFG.d_head,
-                                    SPEC.mlp_dim, CLASSES, SPEC.n_layers,
-                                    ring=ring, fused=True)
-        assert len(led.records) == len(ana.records)
-        for got, want in zip(led.records, ana.records):
-            assert (got.rounds, got.nbytes, got.numel, got.flops, got.tag) \
-                == (want.rounds, want.nbytes, want.numel, want.flops,
-                    want.tag), (got, want)
-            if got.tag == "bw":       # fused flight names are contract too
-                assert got.op == want.op
-
     def test_mirror_is_hermetic_under_wave_scope(self):
-        """The analytic mirror must not inherit ambient wave scaling:
-        per-batch predictions are identical inside a wave_scope."""
-        kw = dict(bsz=BATCH, seq=SEQ, d_model=CFG.d_model,
-                  heads=SPEC.n_heads, kv_heads=CFG.n_kv_heads,
-                  d_head=CFG.d_head, mlp_hidden=SPEC.mlp_dim,
-                  classes=CLASSES, n_layers=SPEC.n_layers,
-                  ring=RING32, fused=True)
-        outside = costs.proxy_exec_cost(**kw)
-        with comm.wave_scope(4):
-            inside = costs.proxy_exec_cost(**kw)
-        assert (inside.rounds, inside.nbytes, inside.flops) == \
-            (outside.rounds, outside.nbytes, outside.flops)
+        """The probe prices ONE batch whatever scope it is called in:
+        inside a wave_scope and under a live WireTape it returns the
+        same stream and appends nothing to the tape."""
+        pp_sh = abstract_shares(CFG, SPEC, SEQ, CLASSES, RING32)
+
+        def probe():
+            return TraceEngine(RING32).probe(pp_sh, CFG, SPEC,
+                                             (BATCH, SEQ, CFG.d_model),
+                                             fused=True)
+
+        outside = probe()
+        tape = comm.WireTape(2)
+        with comm.wave_scope(4), comm.wire_tape_scope(tape):
+            inside = probe()
+        assert inside.records == outside.records
+        assert tape.flights == []
+        streams.assert_matches(
+            streams.Case("tiny", "2pc", "ring32", "fused", BATCH), inside)
 
     def test_fused_mirror_strictly_fewer_rounds_same_bytes(self):
-        kw = dict(bsz=BATCH, seq=SEQ, d_model=CFG.d_model,
-                  heads=SPEC.n_heads, kv_heads=CFG.n_kv_heads,
-                  d_head=CFG.d_head, mlp_hidden=SPEC.mlp_dim,
-                  classes=CLASSES, n_layers=SPEC.n_layers)
+        kw = dict(batch=BATCH, seq=SEQ, classes=CLASSES)
         for ring in RINGS.values():
-            eager = costs.proxy_exec_cost(**kw, ring=ring)
-            fused = costs.proxy_exec_cost(**kw, ring=ring, fused=True)
+            eager = cached_probe(CFG, SPEC, **kw, ring=ring)
+            fused = cached_probe(CFG, SPEC, **kw, ring=ring, fused=True)
             assert fused.rounds < eager.rounds
             assert fused.nbytes == eager.nbytes
             assert fused.lat_rounds == eager.lat_rounds
@@ -270,16 +259,10 @@ class TestExecutedFusedPhase:
         assert np.array_equal(executed["eager"][0], executed["fused"][0])
 
     def test_fused_per_batch_matches_mirror(self, executed):
-        pb = executed["fused"][1].per_batch
-        ana = costs.proxy_exec_cost(8, SEQ, CFG.d_model, SPEC.n_heads,
-                                    CFG.n_kv_heads, CFG.d_head,
-                                    SPEC.mlp_dim, CLASSES, SPEC.n_layers,
-                                    ring=RING32, fused=True)
-        assert len(pb.records) == len(ana.records)
-        for got, want in zip(pb.records, ana.records):
-            assert (got.rounds, got.nbytes, got.numel, got.flops, got.tag) \
-                == (want.rounds, want.nbytes, want.numel, want.flops,
-                    want.tag)
+        """The executed phase's per-batch stream is the pinned one."""
+        streams.assert_matches(
+            streams.Case("tiny", "2pc", "ring32", "fused", 8),
+            executed["fused"][1].per_batch)
 
     def test_fused_phase_pays_fewer_rounds(self, executed):
         led_e = executed["eager"][1].ledger

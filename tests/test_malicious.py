@@ -16,10 +16,11 @@ Contracts:
   4. FORWARD PARITY — all six nonlinearity variants match ClearEngine
      on RING64 under spdz2pc AND aby3trunc, within the same per-variant
      tolerances the semi-honest 2PC path holds.
-  5. MIRROR + EXECUTION — costs.proxy_exec_cost mirrors the TraceEngine
-     probe record-for-record for both new backends x both rings x
-     eager/fused, and an executed WaveExecutor phase passes
-     ledger_agrees with the right party axis and clear-match scores.
+  5. PROBE + EXECUTION — the probe shows the overhead over each
+     semi-honest baseline, and an executed WaveExecutor phase
+     reproduces its pinned per-batch stream
+     (tests/data/probe_streams.txt), passes ledger_agrees, and has the
+     right party axis and clear-match scores.
   6. WRAP STATISTICS (slow) — replicated3pc probabilistic truncation
      measurably wraps at RING32 on large-magnitude values, at a rate
      consistent with the analytic |enc|/2^32 bound; aby3trunc's trunc2
@@ -36,14 +37,16 @@ from repro.configs.paper_targets import TINY_TARGET
 from repro.core import proxy as proxy_mod
 from repro.core.executor import ExecConfig, WaveExecutor
 from repro.core.proxy import ProxySpec
-from repro.engine import (ClearEngine, MPCEngine, TraceEngine, VARIANTS,
-                          abstract_shares, proxy_entropy)
-from repro.mpc import costs, ops as mops, protocols
+from repro.engine import ClearEngine, MPCEngine, VARIANTS, cached_probe, \
+    proxy_entropy
+from repro.mpc import ops as mops, protocols
 from repro.mpc.comm import ledger_scope
 from repro.mpc.protocols.spdz2pc import (MacCheckError, mac_key, mac_scope,
                                          tamper_scope)
 from repro.mpc.ring import RING32, RING64, x64_scope
 from repro.mpc.sharing import reveal, share
+
+from data import probe_streams as streams
 
 CFG = dataclasses.replace(TINY_TARGET, vocab_size=64, n_layers=1,
                           d_model=32, n_heads=2, n_kv_heads=2, d_head=16,
@@ -256,42 +259,23 @@ class TestMaliciousParity:
 
 
 # ---------------------------------------------------------------------------
-# 5. analytic mirror + executed malicious phases
+# 5. the probe + executed malicious phases
 # ---------------------------------------------------------------------------
 
 class TestMaliciousMirror:
-    @pytest.mark.parametrize("fused", [False, True], ids=["eager", "fused"])
-    @pytest.mark.parametrize("ring", list(RINGS.values()), ids=list(RINGS))
-    @pytest.mark.parametrize("proto", MALICIOUS)
-    def test_probe_matches_mirror(self, proto, ring, fused):
-        pp_sh = abstract_shares(CFG, SPEC, SEQ, CLASSES, ring, proto)
-        led = TraceEngine(ring, protocol=proto).probe(
-            pp_sh, CFG, SPEC, (BATCH, SEQ, CFG.d_model), fused=fused)
-        ana = costs.proxy_exec_cost(BATCH, SEQ, CFG.d_model, SPEC.n_heads,
-                                    CFG.n_kv_heads, CFG.d_head,
-                                    SPEC.mlp_dim, CLASSES, SPEC.n_layers,
-                                    ring=ring, protocol=proto, fused=fused)
-        assert len(led.records) == len(ana.records)
-        for got, want in zip(led.records, ana.records):
-            assert (got.rounds, got.nbytes, got.numel, got.flops, got.tag) \
-                == (want.rounds, want.nbytes, want.numel, want.flops,
-                    want.tag), (proto, got, want)
-
     def test_overhead_shape(self):
         """The curve bench_fusion emits, asserted at its source: spdz2pc
         pays rounds (trunc no longer free) and dealer bytes over 2pc;
         aby3trunc pays trunc2 rounds over 3pc but stays dealer-free."""
-        kw = dict(bsz=BATCH, seq=SEQ, d_model=CFG.d_model,
-                  heads=SPEC.n_heads, kv_heads=CFG.n_kv_heads,
-                  d_head=CFG.d_head, mlp_hidden=SPEC.mlp_dim,
-                  classes=CLASSES, n_layers=SPEC.n_layers)
-        base2 = costs.proxy_exec_cost(**kw, ring=RING64, protocol="2pc")
-        mal2 = costs.proxy_exec_cost(**kw, ring=RING64, protocol="spdz2pc")
+        kw = dict(batch=BATCH, seq=SEQ, classes=CLASSES)
+        base2 = cached_probe(CFG, SPEC, **kw, ring=RING64, protocol="2pc")
+        mal2 = cached_probe(CFG, SPEC, **kw, ring=RING64,
+                            protocol="spdz2pc")
         assert mal2.rounds > base2.rounds
         assert mal2.offline_nbytes > base2.offline_nbytes
-        base3 = costs.proxy_exec_cost(**kw, ring=RING32, protocol="3pc")
-        mal3 = costs.proxy_exec_cost(**kw, ring=RING32,
-                                     protocol="aby3trunc")
+        base3 = cached_probe(CFG, SPEC, **kw, ring=RING32, protocol="3pc")
+        mal3 = cached_probe(CFG, SPEC, **kw, ring=RING32,
+                            protocol="aby3trunc")
         assert mal3.rounds > base3.rounds
         assert mal3.offline_nbytes == base3.offline_nbytes == 0
 
@@ -333,19 +317,9 @@ class TestExecutedMaliciousPhase:
 
     def test_per_batch_matches_mirror(self, executed):
         for name in ("eager", "fused"):
-            rep = executed[name][1]
-            ana = costs.proxy_exec_cost(8, SEQ, CFG.d_model, SPEC.n_heads,
-                                        CFG.n_kv_heads, CFG.d_head,
-                                        SPEC.mlp_dim, CLASSES,
-                                        SPEC.n_layers, ring=RING64,
-                                        protocol=executed["proto"],
-                                        fused=rep.fused)
-            pb = rep.per_batch
-            assert len(pb.records) == len(ana.records), name
-            for got, want in zip(pb.records, ana.records):
-                assert (got.rounds, got.nbytes, got.numel, got.flops,
-                        got.tag) == (want.rounds, want.nbytes, want.numel,
-                                     want.flops, want.tag), (name, got, want)
+            streams.assert_matches(
+                streams.Case("tiny", executed["proto"], "ring64", name, 8),
+                executed[name][1].per_batch)
 
     def test_scores_match_clear(self, executed, pp):
         pool = np.random.default_rng(0).integers(0, CFG.vocab_size,

@@ -436,8 +436,9 @@ class TestMultiLayerRing32:
     """Layer >= 2 is where the 2f residual meets a pow2-folded mean
     above the cap: the centering sub must down-trunc the mean with the
     dealer (exact), never a keyless local shift whose share-wrap
-    probability at fb > 2f corrupts rows silently. Pinned by parity AND
-    by the mirror (the align-down is a real, mirrored trunc event)."""
+    probability at fb > 2f corrupts rows silently. Pinned by parity
+    here AND by the two-layer probe streams of tests/test_probe_streams.py
+    (the align-down is a real trunc event on the wire)."""
 
     L = 2
 
@@ -468,24 +469,6 @@ class TestMultiLayerRing32:
         got = np.asarray(reveal(proxy_entropy(eng, pp_sh, cfg, x_sh, spec)))
         # every row, not just the max: wrap corruption is row-sparse
         assert np.abs(got - clear).max() < 5e-3, np.abs(got - clear).max()
-
-    @pytest.mark.parametrize("proto", ["2pc", "3pc"])
-    def test_two_layer_mirror_holds(self, proto):
-        from repro.engine import TraceEngine, abstract_shares
-        from repro.mpc import costs
-        cfg, spec, pp = self._setup()
-        pp_sh = abstract_shares(cfg, spec, 8, 3, RING32, proto)
-        led = TraceEngine(RING32, protocol=proto).probe(
-            pp_sh, cfg, spec, (6, 8, cfg.d_model))
-        ana = costs.proxy_exec_cost(6, 8, cfg.d_model, spec.n_heads,
-                                    cfg.n_kv_heads, cfg.d_head,
-                                    spec.mlp_dim, 3, spec.n_layers,
-                                    ring=RING32, protocol=proto)
-        assert len(led.records) == len(ana.records)
-        for got, want in zip(led.records, ana.records):
-            assert (got.rounds, got.nbytes, got.numel, got.flops, got.tag) \
-                == (want.rounds, want.nbytes, want.numel, want.flops,
-                    want.tag), (got, want)
 
 
 # ---------------------------------------------------------------------------
